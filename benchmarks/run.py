@@ -30,6 +30,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.lpt import lpt_schedule, lpt_schedule_reference
 from repro.core.lp import closed_form_opt, solve_minmax_lp
 from repro.core.theorems import theorem2_optimal_time
@@ -1251,6 +1252,7 @@ def main() -> None:
              "vector-vs-device) and exit (CI)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
     if args.parity_check:
         raise SystemExit(parity_check())
     global _BACKEND

@@ -33,7 +33,7 @@ STEPS, BATCH, SEQ = 24, 4, 64
 
 def main() -> None:
     cfg = get_config("deepseek-7b").reduced()
-    mesh = make_local_mesh()
+    mesh = make_local_mesh(cfg)
     ctx = build_mesh_context(mesh, cfg)
     shape = ShapeSpec("ft", SEQ, BATCH, "train", 1)
     step_fn = jax.jit(

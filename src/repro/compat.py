@@ -1,69 +1,23 @@
-"""Compatibility helpers for jax API drift.
+"""Mesh construction for the installed jax (0.9.0).
 
-``jax.make_mesh`` grew an ``axis_types`` keyword (and ``jax.sharding``
-an ``AxisType`` enum) in newer releases; older runtimes build the same
-Auto-sharded mesh without them. ``jax.shard_map`` graduated from
-``jax.experimental.shard_map`` with ``axis_names=`` replacing the
-experimental ``auto=`` complement. Route mesh construction and shard_map
-through these helpers so the codebase runs on both API generations.
+``make_mesh`` builds Auto-sharded meshes (``jax.make_mesh`` defaults to
+Explicit axes otherwise).
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 
-__all__ = ["make_mesh", "pvary", "shard_map"]
-
-
-def pvary(x, axis_names):
-    """``jax.lax.pvary`` where it exists; identity on older jax (whose
-    shard_map treats values as device-varying already)."""
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_names)
-    return x
+__all__ = ["make_mesh"]
 
 
 def make_mesh(axis_shapes, axis_names, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if (
-        axis_type is not None
-        and "axis_types" in inspect.signature(jax.make_mesh).parameters
-    ):
-        kwargs["axis_types"] = (axis_type.Auto,) * len(tuple(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None, **kwargs):
-    """``jax.shard_map`` on new jax, ``jax.experimental.shard_map`` on old.
-
-    ``axis_names`` (new API: the *manual* axes) passes through on new jax.
-    On old jax the partial-manual form is NOT emulated: the call runs
-    fully manual with ``check_rep=False`` (see the comment below), which
-    matches the auto-axis semantics only when the body never names the
-    non-manual axes — the invariant every shard_map in this repo keeps.
-    """
-    if hasattr(jax, "shard_map"):
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if axis_names is not None and frozenset(mesh.axis_names) != frozenset(axis_names):
-        # Old XLA cannot lower partial-manual shard_map (SPMD partitioner
-        # check failure on manual subgroups). Run fully manual instead:
-        # axes absent from the specs see replicated operands, which matches
-        # the auto-axis semantics whenever the body never names those axes
-        # — true for every shard_map in this repo. check_rep can't prove
-        # the resulting replication, so it must be off.
-        kwargs.setdefault("check_rep", False)
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
+    """``jax.make_mesh`` with Auto axis types on every axis."""
+    axis_names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes),
+        axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices,
     )
+

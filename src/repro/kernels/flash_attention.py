@@ -13,7 +13,13 @@ TPU-native design notes (DESIGN.md §3 hardware adaptation):
   same KV tiles (VMEM reuse instead of materializing repeated KV).
 * Causal / sliding-window masks are applied with ``broadcasted_iota`` over
   absolute positions; fully-masked tiles still execute (documented trade-off
-  — grid pruning is a possible follow-up, see EXPERIMENTS.md §Perf).
+  — grid pruning is a possible follow-up).
+
+**Backward.** ``flash_attention_pallas`` carries a ``jax.custom_vjp``: the
+forward is the Pallas kernel, the backward is :func:`flash_attention_bwd`,
+an explicit ``jnp`` recomputation blocked over KV (one pass for the
+log-sum-exp, one for the gradients), so no ``(T, S)`` score matrix is
+ever materialized whole.
 """
 
 from __future__ import annotations
@@ -26,7 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention_pallas"]
+__all__ = ["flash_attention_bwd", "flash_attention_pallas"]
 
 NEG_INF = -1e30
 
@@ -113,13 +119,42 @@ def flash_attention_pallas(
     """Pallas flash attention. Same contract as ``ref.flash_attention_ref``.
 
     ``q: (B, T, H, hd)``, ``k/v: (B, S, Hkv, hd)`` with ``H % Hkv == 0``.
+    Differentiable: the backward is :func:`flash_attention_bwd`.
     """
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    opts = (causal, q_offset, window, softcap, scale, block_q, block_k, interpret)
+    return _flash_vjp(q, k, v, opts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash_vjp(q, k, v, opts):
+    return _flash_forward(q, k, v, *opts)
+
+
+def _flash_vjp_fwd(q, k, v, opts):
+    out = _flash_forward(q, k, v, *opts)
+    return out, (q, k, v, out)
+
+
+def _flash_vjp_bwd(opts, residuals, d_out):
+    causal, q_offset, window, softcap, scale, _bq, block_k, _interp = opts
+    q, k, v, out = residuals
+    return flash_attention_bwd(
+        q, k, v, out, d_out, causal=causal, q_offset=q_offset, window=window,
+        softcap=softcap, scale=scale, block_k=block_k,
+    )
+
+
+_flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+def _flash_forward(q, k, v, causal, q_offset, window, softcap, scale,
+                   block_q, block_k, interpret):
     b, t, h, hd = q.shape
     s = k.shape[1]
     hkv = k.shape[2]
     rep = h // hkv
-    if scale is None:
-        scale = hd**-0.5
 
     block_q = min(block_q, t)
     block_k = min(block_k, s)
@@ -169,3 +204,95 @@ def flash_attention_pallas(
     if pad_q:
         out = out[:, :t]
     return out
+
+
+def flash_attention_bwd(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    out: jnp.ndarray,
+    d_out: jnp.ndarray,
+    *,
+    causal: bool,
+    q_offset: int,
+    window: Optional[int],
+    softcap: Optional[float],
+    scale: float,
+    block_k: int = 128,
+):
+    """Gradients ``(dq, dk, dv)`` of flash attention, recomputed in ``jnp``.
+
+    The FlashAttention-2 backward over KV blocks of ``block_k``: a first
+    scan rebuilds each query's log-sum-exp, a second one forms the
+    probabilities block by block and accumulates ``dq`` while emitting
+    each block's ``dk``/``dv``. ``out`` is the forward output (for
+    ``D = rowsum(dO * O)``). All math is f32; results take the input dtypes.
+    """
+    b, t, h, hd = q.shape
+    s = k.shape[1]
+    hkv = k.shape[2]
+    rep = h // hkv
+    blk = min(block_k, s)
+    pad = (-s) % blk
+    n_blocks = (s + pad) // blk
+
+    qf = q.astype(jnp.float32).reshape(b, t, hkv, rep, hd) * scale
+    do = d_out.astype(jnp.float32).reshape(b, t, hkv, rep, hd)
+    delta = jnp.sum(do * out.astype(jnp.float32).reshape(b, t, hkv, rep, hd), -1)
+
+    def blocks(x):
+        x = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, pad), (0, 0), (0, 0)))
+        return x.reshape(b, n_blocks, blk, hkv, hd).transpose(1, 0, 2, 3, 4)
+
+    kb, vb = blocks(k), blocks(v)
+    q_pos = q_offset + jnp.arange(t)
+
+    def scores(k_blk, i):
+        raw = jnp.einsum("bthrd,bshd->bthrs", qf, k_blk)
+        capped = raw if softcap is None else jnp.tanh(raw / softcap) * softcap
+        k_pos = i * blk + jnp.arange(blk)
+        mask = k_pos[None, :] < s
+        if causal:
+            mask = mask & (k_pos[None, :] <= q_pos[:, None])
+        if window is not None:
+            mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
+        mask = mask[None, :, None, None, :]  # (1, T, 1, 1, blk)
+        return raw, jnp.where(mask, capped, -jnp.inf), mask
+
+    def lse_step(carry, xs):
+        m, l = carry
+        k_blk, i = xs
+        _, sc, _ = scores(k_blk, i)
+        m_new = jnp.maximum(m, jnp.max(sc, -1))
+        m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+        l = l * jnp.exp(m - m_safe) + jnp.sum(jnp.exp(sc - m_safe[..., None]), -1)
+        return (m_new, l), None
+
+    m0 = jnp.full((b, t, hkv, rep), -jnp.inf, jnp.float32)
+    (m, l), _ = jax.lax.scan(
+        lse_step, (m0, jnp.zeros_like(m0)), (kb, jnp.arange(n_blocks))
+    )
+    lse = jnp.where(l > 0, jnp.where(jnp.isneginf(m), 0.0, m) + jnp.log(l), jnp.inf)
+
+    def grad_step(dq, xs):
+        k_blk, v_blk, i = xs
+        raw, sc, mask = scores(k_blk, i)
+        p = jnp.where(mask, jnp.exp(sc - lse[..., None]), 0.0)
+        dv = jnp.einsum("bthrs,bthrd->bshd", p, do)
+        dp = jnp.einsum("bthrd,bshd->bthrs", do, v_blk)
+        ds = p * (dp - delta[..., None])
+        if softcap is not None:
+            ds = ds * (1.0 - jnp.square(jnp.tanh(raw / softcap)))
+        dq = dq + jnp.einsum("bthrs,bshd->bthrd", ds, k_blk)
+        dk = jnp.einsum("bthrs,bthrd->bshd", ds, qf)
+        return dq, (dk, dv)
+
+    dq, (dk, dv) = jax.lax.scan(
+        grad_step, jnp.zeros_like(qf), (kb, vb, jnp.arange(n_blocks))
+    )
+
+    def unblock(x):
+        return x.transpose(1, 0, 2, 3, 4).reshape(b, n_blocks * blk, hkv, hd)[:, :s]
+
+    dq = (dq * scale).reshape(b, t, h, hd).astype(q.dtype)
+    return dq, unblock(dk).astype(k.dtype), unblock(dv).astype(v.dtype)

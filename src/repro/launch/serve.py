@@ -37,12 +37,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
-from repro.launch.steps import make_decode_step, make_prefill_step
-from repro.launch.train import make_local_mesh
-from repro.models import init_cache, init_params
+from repro.launch.steps import make_decode_step
+from repro.launch.train import apply_cli_cuts, init_sharded_params, make_local_mesh
+from repro.models import init_cache
 from repro.parallel.mesh_view import build_mesh_context
-from repro.parallel.sharding import param_shardings
 
 
 def _sim_fault_spec(kind: str, num_rails: int):
@@ -141,6 +141,8 @@ def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="replace num_layers (depth only; widths kept)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
@@ -182,10 +184,9 @@ def main(argv=None) -> dict:
     if args.gateway:
         return _run_gateway_mode(args)
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    mesh = make_local_mesh()
+    enable_compile_cache()
+    cfg = apply_cli_cuts(get_config(args.arch), args)
+    mesh = make_local_mesh(cfg)
     ctx = build_mesh_context(mesh, cfg)
     max_len = args.prompt_len + args.gen
 
@@ -195,9 +196,8 @@ def main(argv=None) -> dict:
     trace_counts = args.sim_fabric and bool(cfg.num_experts)
 
     key = jax.random.PRNGKey(args.seed)
-    with ctx.mesh:
-        params = init_params(cfg, key)
-        params = jax.tree.map(jax.device_put, params, param_shardings(cfg, ctx, params))
+    with jax.set_mesh(ctx.mesh):
+        params, _ = init_sharded_params(cfg, ctx, key)
         decode = jax.jit(
             make_decode_step(cfg, ctx, return_counts=trace_counts),
             donate_argnums=(1,),
@@ -219,9 +219,11 @@ def main(argv=None) -> dict:
         # one compiled program; a fused prefill path exists for the dry-run).
         t0 = time.time()
         logits = None
+        finite = jnp.bool_(True)  # every logit of every step, reduced on device
         for pos in range(args.prompt_len):
             batch = {"tokens": jnp.asarray(prompts[:, pos : pos + 1], jnp.int32)}
             logits, cache, _ = step((params, cache, batch, jnp.int32(pos)))
+            finite = finite & jnp.isfinite(logits).all()
         t_prefill = time.time() - t0
 
         generated = []
@@ -235,6 +237,7 @@ def main(argv=None) -> dict:
             logits, cache, counts = step(
                 (params, cache, {"tokens": tok}, jnp.int32(args.prompt_len + i))
             )
+            finite = finite & jnp.isfinite(logits).all()
             if counts is not None:
                 step_counts.append(np.asarray(counts))
             if args.temperature > 0:
@@ -249,7 +252,11 @@ def main(argv=None) -> dict:
     print(f"prefill {args.prompt_len} tok x{args.batch}: {t_prefill:.2f}s")
     print(f"decode {args.gen} tok x{args.batch}: {t_gen:.2f}s  ({tput:.1f} tok/s)")
     print("sample:", out_tokens[0][:12])
-    result = {"tokens": out_tokens, "tput": tput}
+    result = {
+        "tokens": out_tokens,
+        "tput": tput,
+        "logits_finite": bool(finite),
+    }
     if args.sim_fabric and args.gen > 0:
         if not step_counts:
             # Dense arch: uniform synthetic routing (the step's batch

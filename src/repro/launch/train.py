@@ -7,6 +7,11 @@ pipeline, mesh view, sharded train step, async checkpointing and restart.
 Example (CPU, ~100M-param class run):
     PYTHONPATH=src python -m repro.launch.train --arch mixtral-8x7b \
         --reduced --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
+
+``--layers N`` cuts depth only (published widths kept), so one layer stack
+of a full-width config fits a chip. On a local mesh, MoE configs lay every
+device on the ``model`` axis, so the mesh view gives them expert
+parallelism (e.g. four chips hold two Mixtral experts each).
 """
 
 from __future__ import annotations
@@ -18,10 +23,12 @@ import time
 import numpy as np
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import Checkpointer, latest_step, restore
-from repro.configs import SHAPES, get_config
-from repro.configs.base import ShapeSpec
+from repro.compile_cache import enable_compile_cache
+from repro.configs import get_config
+from repro.configs.base import ModelConfig, ShapeSpec
 from repro.data import DataConfig, SyntheticTokens
 from repro.launch.mesh import make_production_mesh
 from repro.compat import make_mesh as compat_make_mesh
@@ -29,18 +36,50 @@ from repro.launch.steps import make_train_step
 from repro.models import init_params
 from repro.optim import AdamWConfig, adamw_init, warmup_cosine
 from repro.parallel.mesh_view import build_mesh_context
-from repro.parallel.sharding import param_shardings, to_shardings, opt_state_pspecs
+from repro.parallel.sharding import (
+    batch_pspecs,
+    opt_state_pspecs,
+    param_shardings,
+    to_shardings,
+)
 
 
-def make_local_mesh():
+def make_local_mesh(cfg: ModelConfig):
+    """All local devices on ``data`` — or on ``model`` for MoE configs, so
+    the mesh view can split them into expert-parallel shards."""
     n = len(jax.devices())
-    return compat_make_mesh((n, 1), ("data", "model"))
+    shape = (1, n) if cfg.is_moe else (n, 1)
+    return compat_make_mesh(shape, ("data", "model"))
+
+
+def apply_cli_cuts(cfg: ModelConfig, args) -> ModelConfig:
+    """``--reduced`` (smoke widths) and ``--layers`` (depth only)."""
+    if args.reduced:
+        cfg = cfg.reduced()
+    if args.layers:
+        print(f"reduced: num_layers {cfg.num_layers}->{args.layers}")
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    return cfg
+
+
+def init_sharded_params(cfg: ModelConfig, ctx, key):
+    """Parameters made on the devices in their final shardings (one jitted
+    call, so no full-size f32 initializer temporaries and no host copy)."""
+    abstract = jax.eval_shape(lambda: init_params(cfg, key))
+    shardings = param_shardings(cfg, ctx, abstract)
+    init = jax.jit(lambda k: init_params(cfg, k), out_shardings=shardings)
+    return init(key), shardings
 
 
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="replace num_layers (depth only; widths kept)")
+    ap.add_argument("--dispatch-mode", choices=("dense", "ring", "rails", "spray"),
+                    default=None,
+                    help="override the config's MoE all-to-all dispatch")
     ap.add_argument("--production", action="store_true")
     ap.add_argument("--multipod", action="store_true")
     ap.add_argument("--steps", type=int, default=100)
@@ -93,13 +132,14 @@ def main(argv=None) -> dict:
     )
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
+    enable_compile_cache()
+    cfg = apply_cli_cuts(get_config(args.arch), args)
+    if args.dispatch_mode:
+        cfg = dataclasses.replace(cfg, dispatch_mode=args.dispatch_mode)
     mesh = (
         make_production_mesh(multi_pod=args.multipod)
         if args.production
-        else make_local_mesh()
+        else make_local_mesh(cfg)
     )
     ctx = build_mesh_context(mesh, cfg)
     shape = ShapeSpec("cli", args.seq, args.batch, "train", args.microbatches)
@@ -110,12 +150,22 @@ def main(argv=None) -> dict:
     step_fn = make_train_step(cfg, ctx, shape, opt_cfg)
 
     key = jax.random.PRNGKey(args.seed)
-    with ctx.mesh:
-        params = init_params(cfg, key)
-        p_sh = param_shardings(cfg, ctx, params)
-        params = jax.tree.map(jax.device_put, params, p_sh)
-        opt_state = adamw_init(params)
-        jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
+    with jax.set_mesh(ctx.mesh):
+        params, p_sh = init_sharded_params(cfg, ctx, key)
+        o_sh = to_shardings(ctx, opt_state_pspecs(cfg, ctx, params))
+        opt_state = jax.jit(adamw_init, out_shardings=o_sh)(params)
+        b_specs = batch_pspecs(cfg, ctx, shape)
+        b_sh = to_shardings(ctx, {k: b_specs[k] for k in ("tokens", "labels")})
+        jit_step = jax.jit(
+            step_fn,
+            in_shardings=(p_sh, o_sh, b_sh),
+            out_shardings=(p_sh, o_sh, NamedSharding(ctx.mesh, P())),
+            donate_argnums=(0, 1),
+        )
+    print(
+        f"mesh {dict(ctx.mesh.shape)} ep={ctx.ep} "
+        f"dispatch={cfg.dispatch_mode if cfg.is_moe else 'n/a'}"
+    )
 
     data = SyntheticTokens(
         DataConfig(cfg.vocab_size, args.seq, args.batch, seed=args.seed)
@@ -163,7 +213,7 @@ def main(argv=None) -> dict:
 
     losses = []
     t0 = time.time()
-    with ctx.mesh:
+    with jax.set_mesh(ctx.mesh):
         for step in range(start_step, args.steps):
             batch = {k: jax.numpy.asarray(v) for k, v in data.batch(step).items()}
             params, opt_state, metrics = jit_step(params, opt_state, batch)
@@ -235,7 +285,11 @@ def main(argv=None) -> dict:
             ckpt.wait()
             ckpt.save_async(args.steps, (params, opt_state))
             ckpt.wait()
-    result = {"losses": losses, "final_loss": losses[-1][1] if losses else None}
+    result = {
+        "losses": losses,
+        "final_loss": losses[-1][1] if losses else None,
+        "ep": ctx.ep,
+    }
     if args.fail_at is not None:
         # Data-plane half of the drill on a reference 4x4 fabric (the
         # full sched fabric would take minutes of DES for no extra

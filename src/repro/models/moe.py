@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
-from ..compat import shard_map as compat_shard_map
 from ..core.rails_all_to_all import build_rail_schedule, rails_all_to_all, ring_all_to_all, spray_all_to_all, dense_all_to_all
 from .layers import dense_init
 
@@ -231,7 +230,7 @@ def moe_apply(
         "w_up": P(axis, None, None),
         "w_down": P(axis, None, None),
     }
-    out, aux, counts = compat_shard_map(
+    out, aux, counts = jax.shard_map(
         lambda xs, pr: body(xs, pr),
         mesh=ep_info.mesh,
         in_specs=(P(axis, None, None, None), pspec),

@@ -23,14 +23,17 @@ level on multi-pod fabrics) exactly like the vector backend; per-link
 ``link_busy`` carry is an arrival clamp whose sort keys preserve the
 pre-clamp order, mirroring ``fastsim._busy_clamped``.
 
-**Two scan kernels.** The inner segmented scan has a Pallas kernel —
-grid over blocks of per-link job lanes, a sequential ``fori_loop`` over
-the padded lane depth doing one max/add per position across the block's
-links — and a pure ``lax.associative_scan`` fallback over the flat
-sorted arrays. The Pallas path is selected at import when the backend
-can actually lower it (TPU-style targets); CPU jax compiles the ``lax``
-fallback. ``impl="pallas_interpret"`` forces the kernel through the
-Pallas interpreter so its numerics are testable anywhere.
+**Two scan kernels.** The inner segmented scan runs as a pure
+``lax.associative_scan`` over the flat sorted arrays (:func:`scan_impl`:
+``lax`` on CPU and TPU, the only platforms supported). A Pallas lane
+kernel — grid over blocks of per-link job lanes, a sequential
+``fori_loop`` over the padded lane depth doing one max/add per position
+across the block's links — runs only through the Pallas interpreter
+(``impl="pallas_interpret"``), which keeps its numerics tested. The TPU
+cannot compile it as it stands: Pallas TPU takes no f64 operands (XLA's
+X64 rewrite cannot split a ``tpu_custom_call``), and even in f32 the
+column-at-a-time lane stores are not 128-lane aligned, which Mosaic
+refuses. It waits for a redesign in f32 with links on lanes.
 
 **Tolerance contract, not bit parity.** The associative scan
 re-associates the additions inside a busy period, and simultaneous-finish
@@ -46,9 +49,10 @@ completion.
 to power-of-two buckets (sentinel link ids, zero sizes) so jit traces
 are reused across calls; :func:`simulate_many_device` stacks a list of
 planned simulations to one bucket and runs them through a single
-``vmap``-ed device call. Everything is f64 under the
-``jax.experimental.enable_x64`` context — precision matches the numpy
-backend without flipping the process-global x64 flag.
+``vmap``-ed device call. Everything is f64 under the ``jax.enable_x64``
+context — precision matches the numpy backend without flipping the
+process-global x64 flag. The TPU has no native f64; XLA emulates it
+there, and the tolerance contract above is checked on the chip.
 """
 
 from __future__ import annotations
@@ -60,7 +64,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from .fastsim import (
     ArraySimResult,
@@ -73,7 +76,6 @@ __all__ = [
     "PlannedJobs",
     "check_device_supports",
     "pad_job_arrays",
-    "pallas_available",
     "scan_impl",
     "simulate_chunk_arrays_device",
     "simulate_many_device",
@@ -94,35 +96,18 @@ _MIN_LANE = 128
 # Kernel selection
 
 
-@functools.cache
-def pallas_available() -> bool:
-    """Whether this jax backend can actually lower a Pallas kernel.
-
-    Probes by compiling a trivial ``pallas_call``; CPU jax (the CI / dev
-    environment) fails the probe and falls back to ``lax.associative_scan``.
-    Cached — the probe compiles, so it must run at most once.
-    """
-    try:
-        from jax.experimental import pallas as pl
-
-        def k(x_ref, o_ref):
-            o_ref[...] = x_ref[...] * 2.0
-
-        fn = pl.pallas_call(
-            k, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32)
-        )
-        jax.jit(fn).lower(jnp.zeros((8, 128), jnp.float32)).compile()
-        return True
-    except Exception:
-        return False
-
-
 def scan_impl() -> str:
-    """The default segmented-scan implementation for this process."""
-    return "pallas" if pallas_available() else "lax"
+    """The default segmented-scan implementation: ``lax`` (see the module
+    docstring for why the TPU runs it too). Other platforms are refused."""
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise NotImplementedError(
+            f"the device simulator supports cpu and tpu, not {platform!r}"
+        )
+    return "lax"
 
 
-_IMPLS = ("lax", "pallas", "pallas_interpret")
+_IMPLS = ("lax", "pallas_interpret")
 
 
 # --------------------------------------------------------------------------
@@ -157,10 +142,10 @@ def _lane_scan_kernel(t_ref, b_ref, out_ref):
     bl, depth = t_ref.shape
 
     def body(j, c):
-        t = pl.load(t_ref, (slice(None), pl.dslice(j, 1)))[:, 0]
-        b = pl.load(b_ref, (slice(None), pl.dslice(j, 1)))[:, 0]
+        t = t_ref[:, pl.ds(j, 1)][:, 0]
+        b = b_ref[:, pl.ds(j, 1)][:, 0]
         c = jnp.maximum(b, c + t)
-        pl.store(out_ref, (slice(None), pl.dslice(j, 1)), c[:, None])
+        out_ref[:, pl.ds(j, 1)] = c[:, None]
         return c
 
     jax.lax.fori_loop(
@@ -168,7 +153,7 @@ def _lane_scan_kernel(t_ref, b_ref, out_ref):
     )
 
 
-def _segmented_maxplus_pallas(head, service, b, num_segments, lane_depth, interpret):
+def _segmented_maxplus_pallas(head, service, b, num_segments, lane_depth):
     """Dense-lane Pallas path: scatter sorted jobs into (link, position)
     lanes, scan each lane in the kernel, gather completions back.
 
@@ -204,7 +189,7 @@ def _segmented_maxplus_pallas(head, service, b, num_segments, lane_depth, interp
         ],
         out_specs=pl.BlockSpec((_LANE_BLOCK, lane_depth), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded_segs, lane_depth), service.dtype),
-        interpret=interpret,
+        interpret=True,
     )(lane_t, lane_b)
     return out[seg, pos]
 
@@ -236,7 +221,6 @@ def _level_scan(el, clamped, arrival, tie1, tie2, service, num_links,
     c_s = _segmented_maxplus_lax(head, service_s, a_s + service_s) \
         if impl == "lax" else _segmented_maxplus_pallas(
             head, service_s, a_s + service_s, num_links + 1, lane_depth,
-            interpret=(impl == "pallas_interpret"),
         )
     # Re-derive the final step from the scan carry: start = max(a, c_prev)
     # exactly (the scan's re-associated sum would otherwise leak into the
@@ -423,9 +407,9 @@ def _resolve_impl(impl: str | None) -> str:
 def _lane_depth_for(link_by_level_list, num_links: int) -> int:
     """Static Pallas lane depth: deepest per-(level, link) queue, padded.
 
-    Only consulted on the Pallas paths; the ``lax`` fallback scans the
-    flat sorted arrays and ignores it (pass 0 so the jit cache key stays
-    constant there).
+    Only consulted by the interpreted Pallas kernel; the ``lax`` scan
+    runs on the flat sorted arrays and ignores it (pass 0 so the jit
+    cache key stays constant there).
     """
     deepest = 1
     for lbl in link_by_level_list:
@@ -492,9 +476,8 @@ def simulate_chunk_arrays_device(
 
     Same signature and result type; the scan runs as one jitted device
     call on padded fixed-shape arrays. ``impl`` forces a scan kernel
-    (``lax``, ``pallas``, ``pallas_interpret``) — default auto-selects
-    via :func:`pallas_available`. Parity with the vector backend is float
-    tolerance, not bit-exact (see the module docstring).
+    (``lax`` or ``pallas_interpret``) — default is :func:`scan_impl`.
+    Parity with the vector backend is float tolerance, not bit-exact (see the module docstring).
     """
     check_device_supports(index.topo)
     impl = _resolve_impl(impl)
@@ -522,7 +505,7 @@ def simulate_chunk_arrays_device(
         _lane_depth_for([planned.link_by_level], num_links)
         if impl != "lax" else 0
     )
-    with enable_x64():
+    with jax.enable_x64(True):
         finish, start0, link_volume, link_last, makespan = _scan_single_jit(
             jnp.asarray(lbl), jnp.asarray(psize), jnp.asarray(prelease),
             jnp.asarray(prank), jnp.asarray(index.rate),
@@ -584,7 +567,7 @@ def simulate_many_device(
         _lane_depth_for([p.link_by_level for p in planned], num_links)
         if impl != "lax" else 0
     )
-    with enable_x64():
+    with jax.enable_x64(True):
         finish, start0, link_volume, link_last, makespan = _scan_batch_jit(
             jnp.asarray(lbl), jnp.asarray(size), jnp.asarray(release),
             jnp.asarray(rank), jnp.asarray(rate),

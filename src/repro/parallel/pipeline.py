@@ -20,9 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import pvary as compat_pvary
-from ..compat import shard_map as compat_shard_map
-
 __all__ = ["gpipe", "pipeline_loss"]
 
 
@@ -55,8 +52,12 @@ def gpipe(
         mb_shape = xs.shape[1:]
         # carries become device-varying over the stage axis inside the loop;
         # mark the (replicated) initial values accordingly.
-        carry = compat_pvary(jnp.zeros(mb_shape, xs.dtype), (stage_axis,))
-        outputs = compat_pvary(jnp.zeros((n_mb,) + mb_shape, xs.dtype), (stage_axis,))
+        carry = jax.lax.pcast(
+            jnp.zeros(mb_shape, xs.dtype), (stage_axis,), to="varying"
+        )
+        outputs = jax.lax.pcast(
+            jnp.zeros((n_mb,) + mb_shape, xs.dtype), (stage_axis,), to="varying"
+        )
         perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
 
         def tick(state, t):
@@ -85,7 +86,7 @@ def gpipe(
         )
         return outputs
 
-    return compat_shard_map(
+    return jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),

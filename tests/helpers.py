@@ -19,6 +19,8 @@ def run_multidevice(code: str, devices: int = 8, timeout: int = 600) -> str:
     at 1 device per the dry-run contract.
     """
     env = dict(os.environ)
+    # The N devices are virtual CPU devices: pin the child to the CPU.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(

@@ -96,7 +96,7 @@ def test_compressed_psum_multidevice():
         mesh = compat.make_mesh((4,), ("pod",))
         g = np.random.default_rng(0).normal(size=(4, 64)).astype(np.float32)
 
-        @partial(compat.shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod")))
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod")))
         def sync(g_loc, ef_loc):
             gr = {"w": g_loc[0]}
             efr = {"w": ef_loc[0]}

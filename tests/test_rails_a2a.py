@@ -48,7 +48,7 @@ def test_all_modes_equal_dense_on_devices():
         x = np.random.default_rng(0).normal(size=(E*E, T, D)).astype(np.float32)
 
         def run(mode, **kw):
-            @partial(compat.shard_map, mesh=mesh, in_specs=P("ep"), out_specs=P("ep"))
+            @partial(jax.shard_map, mesh=mesh, in_specs=P("ep"), out_specs=P("ep"))
             def f(xl):
                 return rails_dispatch(xl, "ep", mode=mode, **kw)
             return np.asarray(jax.jit(f)(x))
@@ -62,7 +62,7 @@ def test_all_modes_equal_dense_on_devices():
         # counts-planned schedule also exact
         counts = np.random.default_rng(1).integers(1, 50, (E, E))
         sched = build_rail_schedule(E, 4, num_chunks=3, counts=counts)
-        @partial(compat.shard_map, mesh=mesh, in_specs=P("ep"), out_specs=P("ep"))
+        @partial(jax.shard_map, mesh=mesh, in_specs=P("ep"), out_specs=P("ep"))
         def f2(xl):
             return rails_all_to_all(xl, "ep", sched)
         assert np.array_equal(np.asarray(jax.jit(f2)(x)), ref)
@@ -85,7 +85,7 @@ def test_rails_hlo_has_parallel_streams():
 
         from repro import compat
         mesh = compat.make_mesh((8,), ("ep",))
-        @partial(compat.shard_map, mesh=mesh, in_specs=P("ep"), out_specs=P("ep"))
+        @partial(jax.shard_map, mesh=mesh, in_specs=P("ep"), out_specs=P("ep"))
         def f(xl):
             return rails_dispatch(xl, "ep", mode="rails", num_rails=4, num_chunks=2)
         hlo = jax.jit(f).lower(
