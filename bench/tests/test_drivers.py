@@ -1,0 +1,99 @@
+"""CPU rehearsals of both drivers on the benchmark cut to CPU widths
+(Pallas kernels interpreted; four virtual devices for training), and a
+cell, mix, driver and metric added as new files only."""
+
+import hashlib
+import json
+import shutil
+from pathlib import Path
+
+from conftest import run_child
+
+RUN = """
+import json, sys
+from pathlib import Path
+from bench import run
+result, _ = run.run(Path('.'), {cell!r}, {seed}, {seconds}, False, require_chip=False)
+print(json.dumps(result))
+"""
+
+
+def test_serve_rehearsal(small_tree):
+    out = run_child(RUN.format(cell="serve-chat-b4", seed=2**31 + 11, seconds=2.0), small_tree)
+    assert out["correct"], out
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert set(out["metrics"]) == {"serve_tokens_per_s", "setup_s"}
+    assert out["device"]["count"] == 1
+    assert list(out)[-1] == "checks" and out["checks"]["mean_logit_gap"]["limit"] == 0.035
+
+
+def test_train_rehearsal_on_four_devices(small_tree):
+    out = run_child(RUN.format(cell="train-ep4-rails", seed=2**31 + 12, seconds=2.0),
+                    small_tree, devices=4)
+    assert out["correct"], out
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert set(out["metrics"]) == {"train_tokens_per_s", "setup_s"}
+    assert out["device"]["count"] == 4
+    assert set(out["checks"]) == {"loss_rel", "grad_norm_gap", "delta_norm_gap"}
+
+
+def _digest(root: Path) -> dict:
+    return {str(p.relative_to(root)): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in sorted(root.rglob("*")) if p.is_file() and "__pycache__" not in p.parts}
+
+
+def test_new_cell_mix_driver_and_metric_are_new_files_only(small_tree):
+    """A later PR adds a configuration, a traffic mix, a driver, a limits
+    file and a per-layer metric as new files, plus BENCHMARK.json entries;
+    every file the benchmark already had stays byte for byte."""
+    bench = small_tree / "bench"
+    before = {k: v for k, v in _digest(small_tree).items() if k != "BENCHMARK.json"}
+    cfg = json.loads((bench / "configs" / "mixtral-8x7b-1chip-serve.json").read_text())
+    cfg["model"]["num_layers"] = 1
+    (bench / "configs" / "tiny-serve.json").write_text(json.dumps(cfg))
+    mix = json.loads((bench / "traffic" / "chat-b4-p128-g128.json").read_text())
+    mix.update(driver="serve_echo", batch=2)
+    (bench / "traffic" / "chat-b2-short.json").write_text(json.dumps(mix))
+    (bench / "drivers" / "serve_echo.py").write_text(
+        (bench / "drivers" / "serve.py").read_text())
+    (bench / "workloads" / "serve-tiny-b2.json").write_text(
+        json.dumps({"limits": {"mean_logit_gap": 0.035}}))
+    (bench / "metrics" / "serve.requests_per_window.py").write_text(
+        '"""Requests finished in the traced window."""\n\n\n'
+        "def read(r):\n    return float(r.window['steps'])\n")
+    spec = json.loads((small_tree / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "tiny-serve", "source": "https://example.org/tiny",
+                            "file": "bench/configs/tiny-serve.json", "reduced": [], "why": "t"})
+    spec["workloads"].append({"name": "serve-tiny-b2", "config": "tiny-serve",
+                              "traffic": "chat-b2-short", "chips": 1, "why": "t"})
+    for m in spec["end_to_end"]:
+        if "workloads" in m and "serve-chat-b4" in m["workloads"]:
+            m["workloads"].append("serve-tiny-b2")
+    spec["per_layer"].append({"name": "serve.requests_per_window", "unit": "steps",
+                              "better": "higher", "source": "program_counter", "layer": "t",
+                              "moves": "serve_tokens_per_s", "workloads": ["serve-tiny-b2"]})
+    (small_tree / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    from bench import run
+
+    cell = run.load_cell(small_tree, "serve-tiny-b2")
+    assert cell.model["num_layers"] == 1 and cell.traffic["driver"] == "serve_echo"
+    assert [m["name"] for m in cell.per_layer] == ["serve.requests_per_window"]
+    reader = run.load_module(bench / "metrics" / "serve.requests_per_window.py")
+    assert reader.read(run.Reading(cell, None, {"steps": 3}, {}, 1)) == 3.0
+    out = run_child(RUN.format(cell="serve-tiny-b2", seed=5, seconds=1.0), small_tree)
+    assert out["correct"] and out["attempted"] % 2 == 0
+    after = _digest(small_tree)
+    assert all(after[k] == v for k, v in before.items())
+
+
+def test_refuses_a_cell_it_does_not_know(small_tree):
+    import pytest
+
+    from bench import run
+
+    with pytest.raises(run.Refused):
+        run.load_cell(small_tree, "no-such-cell")
+    shutil.rmtree(small_tree / "bench" / "workloads")
+    with pytest.raises(run.Refused):
+        run.load_cell(small_tree, "serve-chat-b4")
