@@ -44,6 +44,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from repro import obs  # noqa: E402
+
 ARCH = "mixtral-8x7b"
 SEED = 0
 
@@ -92,19 +94,15 @@ def check(cond: bool, msg: str) -> None:
         raise PhaseFailure(msg)
 
 
-class CompileLog:
-    """Backend compile seconds per jitted function, from JAX's own events."""
-
-    def __init__(self):
-        self.events: list[tuple[str, float]] = []
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event: str, secs: float, **kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.events.append((str(kw.get("fun_name", "?")), secs))
-
-    def since(self, mark: int) -> list[tuple[str, float]]:
-        return self.events[mark:]
+def compiled_since(before: dict) -> list[tuple[str, float]]:
+    """Programs compiled since the ``obs.compiles()`` snapshot ``before``,
+    with their compile seconds."""
+    out = []
+    for fun, total in obs.compiles().items():
+        prev = before.get(fun, obs.Total())
+        if total.count > prev.count:
+            out.append((fun, total.seconds - prev.seconds))
+    return out
 
 
 def rel_err(got, want) -> float:
@@ -219,7 +217,7 @@ def _compare(name, policy, dev, vec) -> None:
     check(all(cct[k] <= limit[k] for k in cct), f"{name}: CCT rel {cct}")
 
 
-def phase_simulator(compiles: CompileLog) -> None:
+def phase_simulator() -> None:
     from repro.core.traffic import mixtral_trace_workload
     from repro.netsim import run_collective, run_policy_suite
 
@@ -231,21 +229,21 @@ def phase_simulator(compiles: CompileLog) -> None:
     print(f"simulator: 512 NICs (64x8), {chunks} chunks of "
           f"{chunk_bytes:.0f} B, bucket {SIM_BUCKET}")
     vec = run_collective(tm, "rails", chunk_bytes=chunk_bytes, backend="vector")
-    mark = len(compiles.events)
+    mark = obs.compiles()
     t0 = time.perf_counter()
     dev = run_collective(tm, "rails", chunk_bytes=chunk_bytes, backend="device")
     cold = time.perf_counter() - t0
-    for fun, secs in compiles.since(mark):
+    for fun, secs in compiled_since(mark):
         print(f"simulator: compile bucket {SIM_BUCKET} {fun}: {secs:.1f} s")
     print(f"simulator: first device call (compile + run) {cold:.1f} s")
     _compare("rails", "rails", dev, vec)
 
     tm = mixtral_trace_workload(*SUITE_FABRIC, mode="sparse", seed=SEED)
     chunk_bytes, chunks = _fit_chunk_bytes(tm, SUITE_BUCKET)
-    mark = len(compiles.events)
+    mark = obs.compiles()
     dev = run_policy_suite(tm, SUITE_POLICIES, chunk_bytes=chunk_bytes,
                            seed=SEED, backend="device")
-    for fun, secs in compiles.since(mark):
+    for fun, secs in compiled_since(mark):
         print(f"simulator: compile bucket {SUITE_BUCKET} {fun}: {secs:.1f} s")
     vec = run_policy_suite(tm, SUITE_POLICIES, chunk_bytes=chunk_bytes,
                            seed=SEED, backend="vector")
@@ -291,14 +289,13 @@ def main(argv=None) -> None:
     from repro.compile_cache import enable_compile_cache
 
     print(f"compile cache: {enable_compile_cache()}")
-    compiles = CompileLog()
     if args.four_chips:
         phases = [("train", phase_train_four_chips)]
     else:
         phases = [
             ("kernels", phase_kernels),
             ("serve", phase_serve),
-            ("simulator", lambda: phase_simulator(compiles)),
+            ("simulator", phase_simulator),
         ]
     for name, fn in phases:
         t0 = time.perf_counter()

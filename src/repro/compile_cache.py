@@ -22,7 +22,15 @@ CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def enable_compile_cache() -> str:
-    """Turn the persistent cache on; returns the directory it uses."""
+    """Turn the persistent cache on; returns the directory it uses.
+
+    The cache key also covers the programs' op metadata (each instruction's
+    ``jax.named_scope`` path and source line). Without it, a program from
+    the cache would carry the scopes of whatever code first wrote it into
+    the profiler trace. The price: moving a source line changes the key, so
+    each checkout compiles once, cold.
+    """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

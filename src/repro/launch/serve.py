@@ -37,6 +37,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.launch.steps import make_decode_step
@@ -217,35 +218,37 @@ def main(argv=None) -> dict:
 
         # Prefill via repeated decode steps (token-at-a-time priming keeps
         # one compiled program; a fused prefill path exists for the dry-run).
-        t0 = time.time()
+        # Each phase's time ends when its last step's result is ready.
         logits = None
         finite = jnp.bool_(True)  # every logit of every step, reduced on device
-        for pos in range(args.prompt_len):
-            batch = {"tokens": jnp.asarray(prompts[:, pos : pos + 1], jnp.int32)}
-            logits, cache, _ = step((params, cache, batch, jnp.int32(pos)))
-            finite = finite & jnp.isfinite(logits).all()
-        t_prefill = time.time() - t0
+        with obs.span("serve.prefill") as t_prefill:
+            for pos in range(args.prompt_len):
+                batch = {"tokens": jnp.asarray(prompts[:, pos : pos + 1], jnp.int32)}
+                logits, cache, _ = step((params, cache, batch, jnp.int32(pos)))
+                finite = finite & jnp.isfinite(logits).all()
+            jax.block_until_ready(logits)
 
         generated = []
         step_counts: list[np.ndarray] = []
         step_times: list[float] = []
-        t1 = time.time()
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-        for i in range(args.gen):
-            generated.append(np.asarray(tok))
-            step_times.append(time.time())
-            logits, cache, counts = step(
-                (params, cache, {"tokens": tok}, jnp.int32(args.prompt_len + i))
-            )
-            finite = finite & jnp.isfinite(logits).all()
-            if counts is not None:
-                step_counts.append(np.asarray(counts))
-            if args.temperature > 0:
-                key, sub = jax.random.split(key)
-                tok = jax.random.categorical(sub, logits / args.temperature)[:, None]
-            else:
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-        t_gen = time.time() - t1
+        with obs.span("serve.decode") as t_gen:
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+            for i in range(args.gen):
+                generated.append(np.asarray(tok))
+                step_times.append(time.time())
+                logits, cache, counts = step(
+                    (params, cache, {"tokens": tok}, jnp.int32(args.prompt_len + i))
+                )
+                finite = finite & jnp.isfinite(logits).all()
+                if counts is not None:
+                    step_counts.append(np.asarray(counts))
+                if args.temperature > 0:
+                    key, sub = jax.random.split(key)
+                    tok = jax.random.categorical(sub, logits / args.temperature)[:, None]
+                else:
+                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+            jax.block_until_ready(logits)
+        t_prefill, t_gen = t_prefill.seconds, t_gen.seconds
 
     out_tokens = np.concatenate(generated, axis=1)
     tput = args.batch * args.gen / t_gen if t_gen > 0 else 0.0

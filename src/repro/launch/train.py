@@ -25,6 +25,7 @@ import numpy as np
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.checkpoint import Checkpointer, latest_step, restore
 from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
@@ -211,12 +212,19 @@ def main(argv=None) -> dict:
             controller=controller,
         )
 
+    def compile_s() -> float:
+        return sum(t.seconds for t in obs.compiles().values())
+
     losses = []
-    t0 = time.time()
+    compile0 = compile_s()
+    t0 = first = None  # step time is counted from the end of the first step
     with jax.set_mesh(ctx.mesh):
         for step in range(start_step, args.steps):
             batch = {k: jax.numpy.asarray(v) for k, v in data.batch(step).items()}
             params, opt_state, metrics = jit_step(params, opt_state, batch)
+            if t0 is None:
+                jax.block_until_ready(metrics)
+                t0, first = time.perf_counter(), step
             if (
                 args.fail_at is not None
                 and step == args.fail_at
@@ -274,10 +282,13 @@ def main(argv=None) -> dict:
             if step % args.log_every == 0 or step == args.steps - 1:
                 loss = float(metrics["loss"])
                 losses.append((step, loss))
-                dt = time.time() - t0
+                timed = step - first
+                pace = (f"{(time.perf_counter() - t0) / timed * 1e3:.1f} ms/step"
+                        if timed else "first step")
                 print(
                     f"step {step:5d} loss {loss:8.4f} nll {float(metrics['nll']):7.4f} "
-                    f"gnorm {float(metrics['grad_norm']):7.3f} ({dt:.1f}s)"
+                    f"gnorm {float(metrics['grad_norm']):7.3f} "
+                    f"({pace}, compile {compile_s() - compile0:.1f}s)"
                 )
             if ckpt and step and step % args.ckpt_every == 0:
                 ckpt.save_async(step, (params, opt_state))

@@ -14,6 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..configs.base import ModelConfig
 from ..kernels import ops
 from .layers import apply_mrope, apply_rope, dense_init, rmsnorm, rmsnorm_init, soft_cap
@@ -62,6 +63,7 @@ def _rotary(cfg: ModelConfig, q, k, positions):
     return q, k
 
 
+@obs.scoped(obs.ATTN)
 def attn_forward(
     params: dict,
     cfg: ModelConfig,
@@ -107,6 +109,7 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
     }
 
 
+@obs.scoped(obs.ATTN)
 def attn_decode(
     params: dict,
     cfg: ModelConfig,

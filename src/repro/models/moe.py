@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .. import obs
 from ..configs.base import ModelConfig
 from ..core.rails_all_to_all import build_rail_schedule, rails_all_to_all, ring_all_to_all, spray_all_to_all, dense_all_to_all
 from .layers import dense_init
@@ -68,6 +69,7 @@ def moe_init(key, cfg: ModelConfig, dtype) -> dict:
     }
 
 
+@obs.scoped(obs.ROUTER)
 def _gate(x2: jnp.ndarray, router: jnp.ndarray, cfg: ModelConfig):
     """Top-k routing. ``x2: (..., D)`` -> idx/weights ``(..., k)``, aux, counts."""
     logits = jnp.einsum("...d,de->...e", x2.astype(jnp.float32), router)
@@ -83,6 +85,7 @@ def _gate(x2: jnp.ndarray, router: jnp.ndarray, cfg: ModelConfig):
     return idx, weights.astype(x2.dtype), aux, counts
 
 
+@obs.scoped(obs.DISPATCH)
 def _dispatch_group(x_g, idx_g, w_g, num_experts: int, cap: int):
     """One group's capacity dispatch. ``x_g: (Tg, D)``, ``idx_g/w_g: (Tg, k)``.
 
@@ -104,6 +107,7 @@ def _dispatch_group(x_g, idx_g, w_g, num_experts: int, cap: int):
     return buckets, (flat_e, slot, keep, w_g.reshape(-1))
 
 
+@obs.scoped(obs.COMBINE)
 def _combine_group(buckets_out, meta, tg: int, k: int):
     flat_e, slot, keep, w_flat = meta
     vals = buckets_out[flat_e, slot]  # (Tg*k, D)
@@ -111,6 +115,7 @@ def _combine_group(buckets_out, meta, tg: int, k: int):
     return vals.reshape(tg, k, -1).sum(axis=1)
 
 
+@obs.scoped(obs.EXPERTS)
 def _expert_ffn(xe: jnp.ndarray, params: dict, cfg: ModelConfig, local_slice=None):
     """Grouped FFN. ``xe: (E_loc, M, D)`` -> ``(E_loc, M, D)``.
 
@@ -126,6 +131,7 @@ def _expert_ffn(xe: jnp.ndarray, params: dict, cfg: ModelConfig, local_slice=Non
     return jnp.einsum("gnf,gfd->gnd", act * up, wd)
 
 
+@obs.scoped(obs.A2A)
 def _a2a(payload: jnp.ndarray, axis: Optional[str], cfg: ModelConfig):
     """The paper's collective. ``payload: (ep, G, ...)``, dim0 = peer."""
     if axis is None or payload.shape[0] == 1:
@@ -180,18 +186,22 @@ def _moe_dense_small(x2, params, cfg: ModelConfig):
     all tokens (weights sharded over the expert axis; XLA reduces)."""
     idx, w, aux, counts = _gate(x2, params["router"], cfg)
     e = cfg.num_experts
-    gates = jnp.zeros((x2.shape[0], e), dtype=x2.dtype)
-    gates = jax.vmap(lambda g_row, i_row, w_row: g_row.at[i_row].add(w_row))(
-        gates, idx, w
-    )
-    gate_h = jnp.einsum("nd,edf->nef", x2, params["w_gate"])
-    up_h = jnp.einsum("nd,edf->nef", x2, params["w_up"])
-    act = jax.nn.silu(gate_h) if cfg.act == "silu" else jax.nn.gelu(gate_h)
-    ye = jnp.einsum("nef,efd->ned", act * up_h, params["w_down"])
-    out = jnp.einsum("ned,ne->nd", ye, gates)
+    with jax.named_scope(obs.COMBINE):
+        gates = jnp.zeros((x2.shape[0], e), dtype=x2.dtype)
+        gates = jax.vmap(lambda g_row, i_row, w_row: g_row.at[i_row].add(w_row))(
+            gates, idx, w
+        )
+    with jax.named_scope(obs.EXPERTS):
+        gate_h = jnp.einsum("nd,edf->nef", x2, params["w_gate"])
+        up_h = jnp.einsum("nd,edf->nef", x2, params["w_up"])
+        act = jax.nn.silu(gate_h) if cfg.act == "silu" else jax.nn.gelu(gate_h)
+        ye = jnp.einsum("nef,efd->ned", act * up_h, params["w_down"])
+    with jax.named_scope(obs.COMBINE):
+        out = jnp.einsum("ned,ne->nd", ye, gates)
     return out, aux, counts
 
 
+@obs.scoped(obs.MOE)
 def moe_apply(
     params: dict,
     cfg: ModelConfig,

@@ -24,6 +24,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..configs.base import ModelConfig
 from .attention import attn_decode, attn_forward, attn_init, init_kv_cache
 from .layers import (
@@ -373,16 +374,18 @@ def _vocab_matrix(params, cfg: ModelConfig):
 
 def loss_fn(params, cfg: ModelConfig, batch, ep_info=None, shard_fn: Callable = Identity):
     hidden, aux, _ = forward_hidden(params, cfg, batch, ep_info, shard_fn)
-    nll = chunked_cross_entropy(
-        hidden, _vocab_matrix(params, cfg), batch["labels"],
-        chunk=cfg.xent_chunk, final_softcap=cfg.final_logit_softcap,
-        shard_fn=None if shard_fn is Identity else shard_fn,
-    )
+    with jax.named_scope(obs.HEAD):
+        nll = chunked_cross_entropy(
+            hidden, _vocab_matrix(params, cfg), batch["labels"],
+            chunk=cfg.xent_chunk, final_softcap=cfg.final_logit_softcap,
+            shard_fn=None if shard_fn is Identity else shard_fn,
+        )
     loss = nll + cfg.router_aux_coef * aux["moe_aux"]
     metrics = {"nll": nll, "moe_aux": aux["moe_aux"], "moe_counts": aux["moe_counts"]}
     return loss, metrics
 
 
+@obs.scoped(obs.HEAD)
 def logits_last(params, cfg: ModelConfig, hidden):
     h_last = hidden[:, -1]
     logits = jnp.einsum("bd,dv->bv", h_last, _vocab_matrix(params, cfg)).astype(jnp.float32)
