@@ -25,10 +25,11 @@ from jax.sharding import PartitionSpec as P
 
 from .flash_attention import flash_attention_pallas
 from .grouped_matmul import grouped_matmul_pallas
-from .ref import flash_attention_ref, grouped_matmul_ref, rmsnorm_ref
+from .moe_decode import routed_expert_ffn_pallas
+from .ref import flash_attention_ref, grouped_matmul_ref, rmsnorm_ref, routed_expert_ffn_ref
 from .rmsnorm import rmsnorm_pallas
 
-__all__ = ["flash_attention", "grouped_matmul", "rmsnorm", "kernel_backend"]
+__all__ = ["flash_attention", "grouped_matmul", "rmsnorm", "routed_expert_ffn", "kernel_backend"]
 
 
 def kernel_backend() -> str:
@@ -139,3 +140,36 @@ def rmsnorm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarr
 
         return _on_local_blocks(kernel, (x, weight), split_dims)
     return rmsnorm_ref(x, weight, eps)
+
+
+def routed_expert_ffn(
+    x: jnp.ndarray,
+    gates: jnp.ndarray,
+    w_gate: jnp.ndarray,
+    w_up: jnp.ndarray,
+    w_down: jnp.ndarray,
+    order: jnp.ndarray,
+    n_active: jnp.ndarray,
+    layer: Optional[jnp.ndarray] = None,
+    *,
+    act: str = "silu",
+) -> jnp.ndarray:
+    """Decode-sized MoE FFN; the kernel reads only the experts in
+    ``order[:n_active]`` (``moe_decode.routed_order``). With ``layer`` the
+    weights are stacked over layers and ``layer`` indexes them."""
+    backend = kernel_backend()
+    if backend in ("pallas", "interpret"):
+        operands = (x, gates, w_gate, w_up, w_down, order, n_active)
+        if layer is not None:
+            operands += (layer,)
+
+        def kernel(*operands):
+            return routed_expert_ffn_pallas(*operands, act=act, interpret=backend == "interpret")
+
+        def split_dims(n):
+            dim = 0 if x.shape[0] % n == 0 else None
+            return (dim, dim) + (None,) * (len(operands) - 2), dim
+
+        return _on_local_blocks(kernel, operands, split_dims)
+    weights = (w_gate, w_up, w_down) if layer is None else (w_gate[layer], w_up[layer], w_down[layer])
+    return routed_expert_ffn_ref(x, gates, *weights, act=act)

@@ -12,7 +12,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-__all__ = ["flash_attention_ref", "grouped_matmul_ref", "rmsnorm_ref"]
+__all__ = ["flash_attention_ref", "grouped_matmul_ref", "rmsnorm_ref", "routed_expert_ffn_ref"]
 
 
 def _soft_cap(x: jnp.ndarray, cap: Optional[float]) -> jnp.ndarray:
@@ -128,3 +128,26 @@ def rmsnorm_ref(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.n
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     out = xf * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
     return out.astype(x.dtype)
+
+
+def routed_expert_ffn_ref(
+    x: jnp.ndarray,
+    gates: jnp.ndarray,
+    w_gate: jnp.ndarray,
+    w_up: jnp.ndarray,
+    w_down: jnp.ndarray,
+    *,
+    act: str = "silu",
+) -> jnp.ndarray:
+    """Gate-weighted sum of every expert's FFN for every token.
+
+    ``x: (n, d)``, ``gates: (n, E)`` (zero where a token does not route to
+    the expert), ``w_gate``/``w_up: (E, d, f)``, ``w_down: (E, f, d)``
+    -> ``(n, d)``. The decode-sized MoE: an expert whose gate weight is
+    zero for every token adds nothing, though its weights are read.
+    """
+    gate_h = jnp.einsum("nd,edf->nef", x, w_gate)
+    up_h = jnp.einsum("nd,edf->nef", x, w_up)
+    a = jax.nn.silu(gate_h) if act == "silu" else jax.nn.gelu(gate_h)
+    ye = jnp.einsum("nef,efd->ned", a * up_h, w_down)
+    return jnp.einsum("ned,ne->nd", ye, gates)

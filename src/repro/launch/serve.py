@@ -2,7 +2,9 @@
 
 Small-scale runnable (CPU, reduced config) and production-mesh lowering
 share the same step functions. Requests are batched; decode is a jit'd
-single-token step donated in place.
+single-token step donated in place. For MoE archs it prints the share of
+the (layer, expert) weight sets the decode steps read (the decode MoE
+kernel reads only the experts some token of the step routes to).
 
 ``--sim-fabric`` closes the loop with the RailS simulator: the decode
 loop's *real* per-step expert routing counts (MoE archs; uniform synthetic
@@ -191,10 +193,11 @@ def main(argv=None) -> dict:
     ctx = build_mesh_context(mesh, cfg)
     max_len = args.prompt_len + args.gen
 
-    # Real gating counts exist only for MoE archs; --sim-fabric on dense
-    # models falls back to uniform synthetic counts (batch tokens spread
-    # evenly over 8 pseudo-experts) so the timing replay still works.
-    trace_counts = args.sim_fabric and bool(cfg.num_experts)
+    # Real gating counts and expert reads exist only for MoE archs;
+    # --sim-fabric on dense models falls back to uniform synthetic counts
+    # (batch tokens spread evenly over 8 pseudo-experts) so the timing
+    # replay still works.
+    trace_counts = bool(cfg.num_experts)
 
     key = jax.random.PRNGKey(args.seed)
     with jax.set_mesh(ctx.mesh):
@@ -209,12 +212,12 @@ def main(argv=None) -> dict:
         cache = init_cache(cfg, args.batch, max_len)
 
         def step(logits_cache_args):
-            """One decode call, normalizing the optional counts output."""
+            """One decode call, normalizing the optional counts outputs."""
             out = decode(*logits_cache_args)
             if trace_counts:
                 return out
             logits, new_cache = out
-            return logits, new_cache, None
+            return logits, new_cache, None, None
 
         # Prefill via repeated decode steps (token-at-a-time priming keeps
         # one compiled program; a fused prefill path exists for the dry-run).
@@ -224,11 +227,12 @@ def main(argv=None) -> dict:
         with obs.span("serve.prefill") as t_prefill:
             for pos in range(args.prompt_len):
                 batch = {"tokens": jnp.asarray(prompts[:, pos : pos + 1], jnp.int32)}
-                logits, cache, _ = step((params, cache, batch, jnp.int32(pos)))
+                logits, cache, _, _ = step((params, cache, batch, jnp.int32(pos)))
                 finite = finite & jnp.isfinite(logits).all()
             jax.block_until_ready(logits)
 
         generated = []
+        experts_read = jnp.int32(0)  # (layer, expert) weight sets, summed on device
         step_counts: list[np.ndarray] = []
         step_times: list[float] = []
         with obs.span("serve.decode") as t_gen:
@@ -236,11 +240,13 @@ def main(argv=None) -> dict:
             for i in range(args.gen):
                 generated.append(np.asarray(tok))
                 step_times.append(time.time())
-                logits, cache, counts = step(
+                logits, cache, counts, read = step(
                     (params, cache, {"tokens": tok}, jnp.int32(args.prompt_len + i))
                 )
                 finite = finite & jnp.isfinite(logits).all()
-                if counts is not None:
+                if read is not None:
+                    experts_read = experts_read + read
+                if counts is not None and args.sim_fabric:
                     step_counts.append(np.asarray(counts))
                 if args.temperature > 0:
                     key, sub = jax.random.split(key)
@@ -260,6 +266,13 @@ def main(argv=None) -> dict:
         "tput": tput,
         "logits_finite": bool(finite),
     }
+    if trace_counts and args.gen > 0:
+        held = args.gen * cfg.num_layers * cfg.num_experts
+        share = int(experts_read) / held
+        print(f"experts read over decode: {share:.3f} of {cfg.num_layers} layers x "
+              f"{cfg.num_experts} experts per step "
+              f"({share * cfg.num_experts:.2f} of {cfg.num_experts} per layer)")
+        result["experts_read_share"] = share
     if args.sim_fabric and args.gen > 0:
         if not step_counts:
             # Dense arch: uniform synthetic routing (the step's batch

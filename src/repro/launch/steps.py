@@ -150,9 +150,9 @@ def make_prefill_step(cfg: ModelConfig, ctx: MeshContext, shape: Optional[ShapeS
 
 def make_decode_step(cfg: ModelConfig, ctx: MeshContext, return_counts: bool = False):
     """Decode-step factory. ``return_counts=True`` surfaces the step's
-    per-expert routed-token counts (``(logits, cache, counts)``) — the
-    gating trace `launch/serve.py --sim-fabric` replays onto the
-    simulated rail fabric."""
+    per-expert routed-token counts and expert weight sets read
+    (``(logits, cache, counts, experts_read)``) — the gating trace
+    `launch/serve.py --sim-fabric` replays onto the simulated rail fabric."""
     shard_fn = make_shard_fn(ctx)
     ep_info = ctx.ep_info
 

@@ -17,8 +17,11 @@ Layout strategy (DESIGN.md §4.2):
 * Combine: inverse all-to-all, per-group gather, weighted sum over k.
 
 Decode-sized batches (a handful of tokens) use a dense-EP path instead:
-every expert shard computes its local experts for all tokens and the
-results sum across the expert axis — no dispatch, no capacity drops.
+no dispatch, no capacity drops. With every expert on one shard, a Pallas
+kernel (``kernels/moe_decode.py``) reads the weights of only the experts
+some token routes to; with the experts sharded over the expert axis, each
+shard computes its local experts for all tokens and the results sum
+across the axis.
 
 The gating count vector (the paper's "known traffic matrix" ``D``) is
 returned to the caller for the host-side LPT planner.
@@ -36,9 +39,14 @@ from jax.sharding import PartitionSpec as P
 from .. import obs
 from ..configs.base import ModelConfig
 from ..core.rails_all_to_all import build_rail_schedule, rails_all_to_all, ring_all_to_all, spray_all_to_all, dense_all_to_all
+from ..kernels import ops
+from ..kernels.moe_decode import routed_order
+from ..kernels.ref import routed_expert_ffn_ref
 from .layers import dense_init
 
-__all__ = ["moe_init", "moe_apply", "EpInfo"]
+__all__ = ["moe_init", "moe_apply", "experts_read", "EpInfo", "EXPERT_WEIGHTS"]
+
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
 class EpInfo:
@@ -168,7 +176,7 @@ def _moe_body(x_sh, params, cfg: ModelConfig, ep: int, axis: Optional[str]):
     xe = payload.transpose(2, 0, 1, 3, 4).reshape(e_loc, ep * g * cap, d)
 
     # Inside shard_map the expert weights arrive pre-sliced to E_loc.
-    local = {k: params[k] for k in ("w_gate", "w_up", "w_down")}
+    local = {k: params[k] for k in EXPERT_WEIGHTS}
     ye = _expert_ffn(xe, local, cfg)
 
     back = ye.reshape(e_loc, ep, g, cap, d).transpose(1, 2, 0, 3, 4)
@@ -181,9 +189,17 @@ def _moe_body(x_sh, params, cfg: ModelConfig, ep: int, axis: Optional[str]):
     return out[None], aux[None], counts[None]  # restore manual dim
 
 
-def _moe_dense_small(x2, params, cfg: ModelConfig):
-    """Dense-EP path for decode-sized token counts: all experts computed for
-    all tokens (weights sharded over the expert axis; XLA reduces)."""
+def _dense_small(n: int, ep: int) -> bool:
+    """Whether ``n`` tokens take the dense-EP path (decode-sized batches)."""
+    return n < ep * 8 or n % ep != 0
+
+
+def _moe_dense_small(x2, params, cfg: ModelConfig, ep: int = 1, layer=None):
+    """Dense-EP path for decode-sized token counts, no dispatch. With
+    ``ep == 1`` the kernel reads only the experts some token routes to
+    (from the layer-stacked weights where ``layer`` is given); with the
+    weights sharded over the expert axis (``ep > 1``) every expert is
+    computed for every token and XLA reduces across shards."""
     idx, w, aux, counts = _gate(x2, params["router"], cfg)
     e = cfg.num_experts
     with jax.named_scope(obs.COMBINE):
@@ -191,14 +207,25 @@ def _moe_dense_small(x2, params, cfg: ModelConfig):
         gates = jax.vmap(lambda g_row, i_row, w_row: g_row.at[i_row].add(w_row))(
             gates, idx, w
         )
+    weights = tuple(params[k] for k in EXPERT_WEIGHTS)
     with jax.named_scope(obs.EXPERTS):
-        gate_h = jnp.einsum("nd,edf->nef", x2, params["w_gate"])
-        up_h = jnp.einsum("nd,edf->nef", x2, params["w_up"])
-        act = jax.nn.silu(gate_h) if cfg.act == "silu" else jax.nn.gelu(gate_h)
-        ye = jnp.einsum("nef,efd->ned", act * up_h, params["w_down"])
-    with jax.named_scope(obs.COMBINE):
-        out = jnp.einsum("ned,ne->nd", ye, gates)
+        if ep == 1:
+            order, n_active = routed_order(counts)
+            out = ops.routed_expert_ffn(x2, gates, *weights, order, n_active, layer, act=cfg.act)
+        else:
+            if layer is not None:
+                weights = tuple(w[layer] for w in weights)
+            out = routed_expert_ffn_ref(x2, gates, *weights, act=cfg.act)
     return out, aux, counts
+
+
+def experts_read(cfg: ModelConfig, counts, n_tokens: int, ep: int = 1):
+    """Expert weight sets one ``moe_apply`` layer reads for ``n_tokens``
+    tokens with routed-token ``counts``: on the routed kernel path the
+    experts in use (its ``n_active``), else all ``num_experts``."""
+    if ep == 1 and _dense_small(n_tokens, ep):
+        return jnp.count_nonzero(counts).astype(jnp.int32)
+    return jnp.int32(cfg.num_experts)
 
 
 @obs.scoped(obs.MOE)
@@ -208,17 +235,25 @@ def moe_apply(
     x: jnp.ndarray,
     ep_info: Optional[EpInfo] = None,
     group_tokens: int = 1024,
+    layer=None,
 ):
-    """MoE layer. ``x: (B, T, D)`` -> ``(out, aux_loss, gating_counts)``."""
+    """MoE layer. ``x: (B, T, D)`` -> ``(out, aux_loss, gating_counts)``.
+
+    With ``layer`` (an int32 index) the expert weights in ``params`` are
+    stacked over layers, as a decode step's layer scan passes them so that
+    the routed kernel reads them in place.
+    """
     b, t, d = x.shape
     n = b * t
     ep = ep_info.ep if ep_info is not None else 1
     x2 = x.reshape(n, d)
 
     # Decode-sized batches: dense-EP, no dispatch (and no capacity drops).
-    if n < ep * 8 or n % ep != 0:
-        out, aux, counts = _moe_dense_small(x2, params, cfg)
+    if _dense_small(n, ep):
+        out, aux, counts = _moe_dense_small(x2, params, cfg, ep, layer)
         return out.reshape(b, t, d), aux, counts
+    if layer is not None:
+        params = {**params, **{k: params[k][layer] for k in EXPERT_WEIGHTS}}
 
     rows = n // ep
     tg = min(group_tokens, rows)

@@ -38,7 +38,7 @@ from .layers import (
     soft_cap,
 )
 from .mamba import init_mamba_cache, mamba_decode, mamba_forward, mamba_init
-from .moe import EpInfo, moe_apply, moe_init
+from .moe import EXPERT_WEIGHTS, EpInfo, experts_read, moe_apply, moe_init
 from .xlstm import (
     init_mlstm_cache,
     init_slstm_cache,
@@ -482,11 +482,13 @@ def decode_fn(params, cfg: ModelConfig, cache: dict, tokens, pos, ep_info=None,
     """One decode step. ``tokens: (B, 1)``, ``pos``: scalar position.
 
     Returns ``(logits (B, V-softcapped), new_cache)``, or with
-    ``return_counts=True`` ``(logits, new_cache, moe_counts)`` where
-    ``moe_counts`` is the step's per-expert routed-token counts summed
+    ``return_counts=True`` ``(logits, new_cache, moe_counts, experts_read)``
+    where ``moe_counts`` is the step's per-expert routed-token counts summed
     over layers (``(num_experts,)`` int32; all zeros for non-MoE
     families) — the real gating trace the serving-path fabric replay
-    (``launch/serve.py --sim-fabric``) consumes.
+    (``launch/serve.py --sim-fabric``) consumes — and ``experts_read``
+    (int32) the (layer, expert) weight sets the step read
+    (``moe.experts_read``), summed over layers.
     """
     dt = dtype_of(cfg)
     x = params["embed"][tokens]
@@ -496,6 +498,7 @@ def decode_fn(params, cfg: ModelConfig, cache: dict, tokens, pos, ep_info=None,
     bl = params["blocks"]
     new_cache: dict = {}
     moe_counts = jnp.zeros((max(cfg.num_experts, 1),), jnp.int32)
+    read = jnp.int32(0)
 
     if fam in ("dense", "vlm", "moe"):
         is_moe = fam == "moe"
@@ -532,19 +535,31 @@ def decode_fn(params, cfg: ModelConfig, cache: dict, tokens, pos, ep_info=None,
                 pair, bl, {"local": cache["local"], "global": cache["global"]},
                 x, cfg.num_layers // 2,
             )
-        elif is_moe and return_counts:
-            # Thread a per-expert count accumulator through the layer-scan
-            # carry: the gating trace of this decode step, summed over
-            # layers — what forward_hidden reports for training steps.
-            def body_counts(carry, p, c):
-                xc, cnts = carry
+        elif is_moe:
+            # The expert weights stay stacked over layers, out of the scan's
+            # per-layer slices: the decode MoE kernel reads a layer's experts
+            # from the stack by index, where a slice that fed it would be
+            # copied out first. With ``return_counts`` the carry also sums
+            # per-expert counts and expert weight sets read over layers: the
+            # step's gating trace, as forward_hidden reports for training.
+            ep = ep_info.ep if ep_info is not None else 1
+            experts = {k: bl["moe"][k] for k in EXPERT_WEIGHTS}
+            per_layer = {**bl, "moe": {k: v for k, v in bl["moe"].items() if k not in experts}}
+
+            def body(carry, layer_p, c):
+                xc, cnts, rd = carry
+                i, p = layer_p
                 h, c = attn_decode(p["attn"], cfg, rmsnorm(xc, p["ln1"], cfg.rms_eps),
                                    c, pos, window=_window_for(cfg, "swa"))
                 xc = xc + h
-                out, _a, cnt = moe_apply(p["moe"], cfg, rmsnorm(xc, p["ln2"], cfg.rms_eps), ep_info)
-                return (xc + out, cnts + cnt), c
-            (x, moe_counts), kv = _scan_layers_inplace(
-                body_counts, bl, cache["kv"], (x, moe_counts), cfg.num_layers
+                out, _a, cnt = moe_apply({**p["moe"], **experts}, cfg,
+                                         rmsnorm(xc, p["ln2"], cfg.rms_eps), ep_info, layer=i)
+                if return_counts:
+                    cnts, rd = cnts + cnt, rd + experts_read(cfg, cnt, tokens.size, ep)
+                return (xc + out, cnts, rd), c
+            (x, moe_counts, read), kv = _scan_layers_inplace(
+                body, (jnp.arange(cfg.num_layers), per_layer), cache["kv"],
+                (x, moe_counts, read), cfg.num_layers,
             )
             new_cache = {"kv": kv}
         else:
@@ -552,10 +567,7 @@ def decode_fn(params, cfg: ModelConfig, cache: dict, tokens, pos, ep_info=None,
                 h, c = attn_decode(p["attn"], cfg, rmsnorm(xc, p["ln1"], cfg.rms_eps),
                                    c, pos, window=_window_for(cfg, "swa"))
                 xc = xc + h
-                if is_moe:
-                    out, _a, _c = moe_apply(p["moe"], cfg, rmsnorm(xc, p["ln2"], cfg.rms_eps), ep_info)
-                else:
-                    out = mlp_apply(p["mlp"], rmsnorm(xc, p["ln2"], cfg.rms_eps), cfg.act)
+                out = mlp_apply(p["mlp"], rmsnorm(xc, p["ln2"], cfg.rms_eps), cfg.act)
                 return xc + out, c
             x, kv = _scan_layers_inplace(body, bl, cache["kv"], x, cfg.num_layers)
             new_cache = {"kv": kv}
@@ -630,5 +642,5 @@ def decode_fn(params, cfg: ModelConfig, cache: dict, tokens, pos, ep_info=None,
     x = rmsnorm(x, params["final_norm"], cfg.rms_eps)
     logits = logits_last(params, cfg, x)
     if return_counts:
-        return logits, new_cache, moe_counts
+        return logits, new_cache, moe_counts, read
     return logits, new_cache

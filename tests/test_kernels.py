@@ -8,7 +8,13 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.grouped_matmul import grouped_matmul_pallas
-from repro.kernels.ref import flash_attention_ref, grouped_matmul_ref, rmsnorm_ref
+from repro.kernels.moe_decode import routed_expert_ffn_pallas, routed_order
+from repro.kernels.ref import (
+    flash_attention_ref,
+    grouped_matmul_ref,
+    rmsnorm_ref,
+    routed_expert_ffn_ref,
+)
 from repro.kernels.rmsnorm import rmsnorm_pallas
 
 
@@ -107,6 +113,80 @@ def test_ops_dispatch_env(monkeypatch):
     assert ops.kernel_backend() in ("ref", "pallas")
 
 
+# -- the decode MoE kernel: only the routed experts' weights are read --------
+
+
+def _routed_operands(n, e, k, d, f, dtype, routes=None, seed=0):
+    """Tokens, gates and expert weights; ``routes`` (n, k) fixes the
+    experts each token routes to, else they are drawn at random."""
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(n, d)), dtype)
+    wg, wu = (jnp.asarray(rng.normal(size=(e, d, f)) * d**-0.5, dtype) for _ in range(2))
+    wd = jnp.asarray(rng.normal(size=(e, f, d)) * f**-0.5, dtype)
+    if routes is None:
+        routes = np.stack([rng.choice(e, size=k, replace=False) for _ in range(n)])
+    routes = np.asarray(routes)
+    w = rng.uniform(0.1, 1.0, size=routes.shape)
+    gates = np.zeros((n, e))
+    gates[np.arange(n)[:, None], routes] = w / w.sum(-1, keepdims=True)
+    counts = np.bincount(routes.ravel(), minlength=e)
+    return x, jnp.asarray(gates, dtype), wg, wu, wd, jnp.asarray(counts, jnp.int32)
+
+
+ROUTED_CASES = {
+    # name: (n, E, top_k, d, f, block_f, routes)
+    "all-routed": (4, 8, 2, 32, 256, 128, [[0, 1], [2, 3], [4, 5], [6, 7]]),
+    "one-routed": (4, 8, 1, 32, 256, 128, [[5], [5], [5], [5]]),
+    "n1-e8-top2": (1, 8, 2, 64, 256, None, None),
+    "n4-e8-top2": (4, 8, 2, 32, 256, 128, None),
+    "n7-e8-top2": (7, 8, 2, 32, 128, None, None),
+    "n4-e16-top4": (4, 16, 4, 32, 256, 128, None),
+    "n7-e16-top4": (7, 16, 4, 64, 128, None, None),
+}
+
+
+@pytest.mark.parametrize("case", list(ROUTED_CASES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_routed_expert_ffn_matches_oracle(case, dtype):
+    n, e, k, d, f, block_f, routes = ROUTED_CASES[case]
+    x, gates, wg, wu, wd, counts = _routed_operands(n, e, k, d, f, dtype, routes)
+    order, n_active = routed_order(counts)
+    got = routed_expert_ffn_pallas(x, gates, wg, wu, wd, order, n_active,
+                                   block_f=block_f, interpret=True)
+    want = routed_expert_ffn_ref(*(a.astype(jnp.float32) for a in (x, gates, wg, wu, wd)))
+    assert got.shape == (n, d) and got.dtype == dtype
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got.astype(jnp.float32), want, atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize(
+    "counts", [[2, 0, 0, 0, 1, 0, 1, 4], [0, 0, 0, 3, 0, 0, 0, 0], [1] * 8, [0, 5, 0, 0, 2, 0, 0, 0]]
+)
+def test_routed_order_puts_used_experts_first(counts):
+    order, n_active = routed_order(jnp.asarray(counts, jnp.int32))
+    order, n_active = np.asarray(order), int(n_active[0])
+    used = [i for i, c in enumerate(counts) if c]
+    assert n_active == np.count_nonzero(counts)
+    assert order[:n_active].tolist() == used
+    assert (order[n_active:] == used[-1]).all()
+
+
+def test_routed_expert_ffn_vjp_matches_oracle_grad():
+    x, gates, wg, wu, wd, counts = _routed_operands(3, 8, 2, 32, 256, jnp.float32)
+    order, n_active = routed_order(counts)
+    probe = jnp.asarray(np.random.default_rng(6).normal(size=x.shape), jnp.float32)
+
+    def loss(ffn):
+        return lambda *a: jnp.sum(ffn(*a) * probe)
+
+    args = (x, gates, wg, wu, wd)
+    got = jax.grad(loss(lambda *a: routed_expert_ffn_pallas(
+        *a, order, n_active, block_f=128, interpret=True)), argnums=tuple(range(5)))(*args)
+    want = jax.grad(loss(routed_expert_ffn_ref), argnums=tuple(range(5)))(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=1e-5, rtol=1e-5)
+
+
 # -- custom VJPs: the kernels' backward passes against jax.grad of the oracles
 
 
@@ -171,7 +251,19 @@ rng = np.random.default_rng(5)
 def arr(*shape):
     return jnp.asarray(rng.normal(size=shape), jnp.float32)
 
-if {kernel!r} == "flash":
+if {kernel!r} == "routed":
+    from repro.kernels.moe_decode import routed_order
+    from repro.kernels.ref import routed_expert_ffn_ref
+    n, e, d, f = {shape}
+    gates = jax.nn.softmax(arr(n, e), axis=-1) * (arr(n, e) > 0)
+    # Weights at moe_init's scale.
+    args = (arr(n, d), gates, arr(e, d, f) * d**-0.5, arr(e, d, f) * d**-0.5,
+            arr(e, f, d) * f**-0.5)
+    probe = arr(n, d)
+    routes = routed_order(jnp.sum(gates > 0, axis=0))
+    fn = lambda *a: ops.routed_expert_ffn(*a, *routes)
+    oracle = routed_expert_ffn_ref
+elif {kernel!r} == "flash":
     b, h, hkv, t, hd = {shape}
     args = (arr(b, t, h, hd), arr(b, t, hkv, hd), arr(b, t, hkv, hd))
     probe = arr(b, t, h, hd)
@@ -209,6 +301,8 @@ print("OK")
         ("flash", (1, 6, 2, 32, 16)),  # neither splits: replicated operands
         ("rmsnorm", (8, 16, 64)),  # rows split
         ("rmsnorm", (3, 64)),  # replicated
+        ("routed", (4, 8, 32, 128)),  # tokens split, weights replicated
+        ("routed", (3, 8, 32, 128)),  # replicated
     ],
 )
 def test_kernel_grads_under_four_device_mesh(kernel, shape):

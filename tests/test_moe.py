@@ -112,3 +112,75 @@ def test_distributed_matches_local(mode):
         devices=8,
     )
     assert "OK" in out
+
+
+# -- decode-sized batches: the routed-expert kernel -----------------------------
+
+
+@pytest.mark.parametrize("n", [1, 4, 7])
+def test_dense_small_kernel_matches_einsum(monkeypatch, n):
+    """With the experts on one shard the decode path runs the routed-expert
+    kernel (interpret mode here); it equals the every-expert einsum that
+    the expert-sharded path keeps."""
+    from repro.models.moe import _moe_dense_small
+
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    params = _params(CFG)
+    x = jax.random.normal(jax.random.PRNGKey(6), (n, CFG.d_model))
+    got, aux, counts = _moe_dense_small(x, params, CFG)
+    want, want_aux, want_counts = _moe_dense_small(x, params, CFG, ep=2)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5)
+    assert float(aux) == float(want_aux)
+    assert (np.asarray(counts) == np.asarray(want_counts)).all()
+
+
+def test_decode_experts_read_counts_routed_experts(monkeypatch):
+    """``experts_read`` of a one-layer decode step is the kernel's
+    ``n_active``: the experts some token routes to."""
+    import dataclasses
+
+    from repro.models import decode_fn, init_cache, init_params
+
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    cfg = dataclasses.replace(CFG, num_layers=1, num_experts=8)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tok = jnp.asarray([[3], [11]], jnp.int32)
+    _logits, _cache, counts, read = decode_fn(
+        params, cfg, init_cache(cfg, 2, 8), tok, 0, return_counts=True
+    )
+    assert int(read) == np.count_nonzero(np.asarray(counts))
+    assert 2 <= int(read) <= 4
+
+
+def test_dense_small_keeps_einsum_under_expert_parallelism():
+    """With the expert weights sharded (ep > 1) the decode path keeps the
+    every-expert einsum, no kernel, and equals the one-shard kernel path."""
+    out = run_multidevice(
+        """
+        import os
+        os.environ["REPRO_PALLAS"] = "interpret"
+        import jax, jax.numpy as jnp
+        from repro import compat
+        from repro.configs import get_config
+        from repro.models.moe import moe_apply, moe_init, EpInfo
+
+        cfg = get_config("mixtral-8x7b").reduced()
+        params = moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
+        x = jax.random.normal(jax.random.PRNGKey(7), (4, 1, cfg.d_model))
+        one = lambda p, xx: moe_apply(p, cfg, xx)
+        assert "pallas_call" in str(jax.make_jaxpr(one)(params, x))
+        ref, _, ref_counts = one(params, x)
+
+        mesh = compat.make_mesh((1, 4), ("data", "expert"))
+        sharded = jax.jit(lambda p, xx: moe_apply(p, cfg, xx, EpInfo(mesh, "expert", 4)))
+        with jax.set_mesh(mesh):
+            assert "pallas_call" not in str(jax.make_jaxpr(sharded)(params, x))
+            out, _, counts = sharded(params, x)
+        err = float(jnp.abs(out - ref).max())
+        assert err < 2e-4, err
+        assert (counts == ref_counts).all()
+        print("OK", err)
+        """,
+        devices=4,
+    )
+    assert "OK" in out

@@ -62,7 +62,10 @@ def test_decode_step_products_are_scoped():
     unscoped = [n for n in products if n is None or not components(n) & layers]
     assert not unscoped, unscoped
     found = set().union(*(components(n) for n in products))
-    assert {obs.ATTN, obs.MOE, obs.HEAD, obs.ROUTER, obs.EXPERTS, obs.COMBINE} <= found
+    assert {obs.ATTN, obs.MOE, obs.HEAD, obs.ROUTER, obs.EXPERTS} <= found
+    # The decode MoE's gate-weighted sum lies inside the expert FFN
+    # (``routed_expert_ffn``); ``combine`` builds the per-token gates.
+    assert any(n and obs.COMBINE in components(n) for _, n in instructions(text))
 
 
 TRAIN_HLO = """

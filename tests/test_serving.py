@@ -364,7 +364,7 @@ def test_decode_fn_returns_real_gating_counts():
     params = init_params(cfg, jax.random.PRNGKey(0))
     cache = init_cache(cfg, 2, 8)
     tok = jnp.ones((2, 1), jnp.int32)
-    logits, cache2, counts = jax.jit(
+    logits, cache2, counts, _read = jax.jit(
         lambda p, c, t: decode_fn(p, cfg, c, t, 0, return_counts=True)
     )(params, cache, tok)
     assert logits.shape == (2, cfg.vocab_size)
