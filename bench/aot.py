@@ -37,10 +37,12 @@ def _abstract(tree, shardings):
                         tree, shardings)
 
 
-def train(cell, devices) -> dict:
+def lower_train_step(cell, devices):
+    """The train driver's training step (``bench/drivers/train.py``), with
+    its shardings and donation, lowered from shapes alone; and its mesh
+    context."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.compat import make_mesh
@@ -52,14 +54,12 @@ def train(cell, devices) -> dict:
     from repro.parallel.sharding import (batch_pspecs, opt_state_pspecs, param_shardings,
                                          to_shardings)
 
-    ref = cell.reference
     t, o = cell.traffic, cell.config["optimizer"]
     cfg = ModelConfig(**cell.model)
     ctx = build_mesh_context(make_mesh((1, len(devices)), ("data", "model"), devices=devices), cfg)
     shape = ShapeSpec("bench", t["seq_len"], t["batch"], "train", t["microbatches"])
     step = make_train_step(cfg, ctx, shape, AdamWConfig(
         learning_rate=warmup_cosine(o["peak_lr"], o["warmup_steps"], o["total_steps"])))
-    out = {}
     with jax.set_mesh(ctx.mesh):
         params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
         p_sh = param_shardings(cfg, ctx, params)
@@ -67,13 +67,25 @@ def train(cell, devices) -> dict:
         opt = jax.eval_shape(adamw_init, params)
         b_specs = batch_pspecs(cfg, ctx, shape)
         b_sh = to_shardings(ctx, {k: b_specs[k] for k in ("tokens", "labels")})
-        batch = {k: jax.ShapeDtypeStruct((t["batch"], t["seq_len"]), jnp.int32, sharding=b_sh[k])
+        batch = {k: jax.ShapeDtypeStruct((t["batch"], t["seq_len"]), jnp.int32)
                  for k in ("tokens", "labels")}
-        compiled = jax.jit(
+        lowered = jax.jit(
             step, in_shardings=(p_sh, o_sh, b_sh),
             out_shardings=(p_sh, o_sh, NamedSharding(ctx.mesh, P())), donate_argnums=(0, 1),
-        ).lower(_abstract(params, p_sh), _abstract(opt, o_sh), batch).compile()
-    out["program_train_step"] = _bytes(compiled)
+        ).lower(_abstract(params, p_sh), _abstract(opt, o_sh), batch)
+    return lowered, ctx
+
+
+def train(cell, devices) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    ref = cell.reference
+    t, o = cell.traffic, cell.config["optimizer"]
+    lowered, ctx = lower_train_step(cell, devices)
+    compiled = lowered.compile()
+    out = {"program_train_step": _bytes(compiled)}
     out["program_has_pallas"] = "tpu_custom_call" in compiled.as_text()
 
     arch = ref.Arch.from_model({**cell.model, **cell.config["assumed"]}, ctx.ep)

@@ -19,7 +19,12 @@ whose scope path the layer is a component (``jvp(moe)`` and
 program. ``other`` is the program's device time per run less the union of
 every scoped op: pre-norms, embedding, residual adds, the layer scan's
 weight slicing and copies. The four add up to the program's device time
-per run.
+per run. A scope below a layer (``moe/a2a`` in ``SUBSCOPES``) is read the
+same way, as the union of the ops on whose scope path its components lie
+in that order; it is part of its layer's time, not beside it.
+
+The serve driver's decode step and the train driver's training step are
+rebuilt; a cell of any other driver gets no split.
 """
 
 from __future__ import annotations
@@ -28,23 +33,39 @@ import re
 
 from bench.trace import Trace, is_container, union_ns
 
-__all__ = ["LAYERS", "layer_of", "scope_map", "step_scopes", "split_ms", "read_ms"]
+__all__ = ["LAYERS", "SUBSCOPES", "layer_of", "in_scope", "scope_map", "step_scopes", "split_ms",
+           "read_ms"]
 
 LAYERS = ("attn", "moe", "head")
+SUBSCOPES = ("moe/a2a",)
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=]+)\s*=")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _WRAPPED = re.compile(r"[\w\-]+\((.*)\)")
 
 
-def layer_of(op_name: str) -> str | None:
-    """The first of ``LAYERS`` on the scope path ``op_name``, else ``None``."""
+def _parts(op_name: str) -> list[str]:
+    """The scope path's components, autodiff wrappers (``jvp(moe)``,
+    ``transpose(jvp(moe))``) taken off."""
+    parts = []
     for part in re.split(r"[/;]", op_name):
         while (m := _WRAPPED.fullmatch(part)):
             part = m.group(1)
-        if part in LAYERS:
-            return part
-    return None
+        parts.append(part)
+    return parts
+
+
+def layer_of(op_name: str) -> str | None:
+    """The first of ``LAYERS`` on the scope path ``op_name``, else ``None``."""
+    return next((part for part in _parts(op_name) if part in LAYERS), None)
+
+
+def in_scope(op_name: str, path: str) -> bool:
+    """Whether the components of ``path`` (``moe/a2a``) lie on the scope
+    path ``op_name`` in that order, with any scopes between them
+    (``moe/shard_map/a2a``)."""
+    parts = iter(_parts(op_name))
+    return all(want in parts for want in path.split("/"))
 
 
 def scope_map(hlo_text: str) -> dict[str, str]:
@@ -86,30 +107,42 @@ def _serve_step_text(cell, devices) -> str:
         return step.lower(params, cache, tokens, pos).compile().as_text()
 
 
+def _train_step_text(cell, devices) -> str:
+    """The train driver's training step (``bench/drivers/train.py``), with
+    its shardings and donation, compiled from shapes alone."""
+    from bench.aot import lower_train_step
+
+    return lower_train_step(cell, devices)[0].compile().as_text()
+
+
+_STEP_TEXT = {"serve": _serve_step_text, "train": _train_step_text}
 _built: dict[tuple[str, int], dict[str, str]] = {}  # one compile per process
 
 
 def step_scopes(reading) -> dict[str, str] | None:
     """``scope_map`` of the reading's step program; ``None`` for a cell of
-    another driver than ``serve``."""
+    a driver whose step is not rebuilt here."""
     import jax
 
     cell = reading.cell
-    if cell.traffic["driver"] != "serve":
+    text = _STEP_TEXT.get(cell.traffic["driver"])
+    if text is None:
         return None
     key = (cell.name, reading.chips)
     if key not in _built:
-        _built[key] = scope_map(_serve_step_text(cell, jax.devices()[:reading.chips]))
+        _built[key] = scope_map(text(cell, jax.devices()[:reading.chips]))
     return _built[key]
 
 
 def split_ms(trace: Trace, module: str, scopes: dict[str, str]) -> dict[str, float] | None:
-    """Milliseconds per run of program ``module`` in each of ``LAYERS`` and
-    ``other``, mean over the traced devices. ``None`` where no op of the
-    program carries a layer's scope, or where an op's name is not in
-    ``scopes`` (the trace ran another program)."""
+    """Milliseconds per run of program ``module`` in each of ``LAYERS``,
+    ``other`` and each of ``SUBSCOPES``, mean over the traced devices.
+    ``None`` where no op of the program carries a layer's scope, or where
+    an op's name is not in ``scopes`` (the trace ran another program)."""
     lo, hi = trace.window
     layer = {name: layer_of(op) for name, op in scopes.items()}
+    sub = {path: {name for name, op in scopes.items() if in_scope(op, path)}
+           for path in SUBSCOPES}
     per_dev = []
     for dev in sorted(trace.devices):
         runs = [(max(o.start, lo), min(o.end, hi)) for o in trace.modules.get(dev, ())
@@ -129,25 +162,39 @@ def split_ms(trace: Trace, module: str, scopes: dict[str, str]) -> dict[str, flo
                     continue
                 if o.name not in layer:
                     return None
-                spans.setdefault(layer[o.name], []).append((max(o.start, s), min(o.end, e)))
+                iv = (max(o.start, s), min(o.end, e))
+                spans.setdefault(layer[o.name], []).append(iv)
+                for path, names in sub.items():
+                    if o.name in names:
+                        spans.setdefault(path, []).append(iv)
         if not any(name in spans for name in LAYERS):
             return None
         total = sum(e - s for s, e in runs)
         scoped = [iv for name in LAYERS for iv in spans.get(name, ())]
-        ns = {name: union_ns(spans.get(name, ()), (lo, hi)) for name in LAYERS}
+        ns = {name: union_ns(spans.get(name, ()), (lo, hi)) for name in (*LAYERS, *SUBSCOPES)}
         ns["other"] = total - union_ns(scoped, (lo, hi))
         per_dev.append({k: v / len(runs) * 1e-6 for k, v in ns.items()})
     if not per_dev:
         return None
-    return {k: sum(d[k] for d in per_dev) / len(per_dev) for k in (*LAYERS, "other")}
+    return {k: sum(d[k] for d in per_dev) / len(per_dev) for k in per_dev[0]}
+
+
+_last: list = []  # [trace, module, scopes, split] of the newest split
 
 
 def read_ms(reading, layer: str) -> float | None:
-    """A per-layer metric's reading: ``split_ms`` of the cell's step program."""
+    """A per-layer metric's reading: ``split_ms`` of the cell's step program
+    in ``layer``, one of ``LAYERS``, ``other`` or ``SUBSCOPES``."""
     module = reading.window.get("step_module")
     if not module or not any(o.name == module for mods in reading.trace.modules.values()
                              for o in mods):
         return None
     scopes = step_scopes(reading)
-    split = split_ms(reading.trace, module, scopes) if scopes else None
+    if not scopes:
+        return None
+    # Every layer's reader of one run shares one split of its trace.
+    if not (_last and _last[0] is reading.trace and _last[1] == module and _last[2] is scopes):
+        _last[:] = [reading.trace, module, scopes, split_ms(reading.trace, module, scopes)]
+    split = _last[3]
     return None if split is None else split[layer]
+

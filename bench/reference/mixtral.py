@@ -39,6 +39,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = [
@@ -353,8 +354,8 @@ class TrainReference:
     the batch split; everything else replicated.
 
     A step is two programs: the gradients, then the AdamW update. While
-    the gradients are computed, AdamW's moments wait in the host's memory
-    (where the devices have one), so that the float32 activations, the
+    the gradients are computed, AdamW's moments wait in the host's memory,
+    one plain copy per device shard, so that the float32 activations, the
     float32 gradients and the moments are never on a device together."""
 
     def __init__(self, a: Arch, opt: AdamW, devices, precision: str = "f32",
@@ -377,11 +378,6 @@ class TrainReference:
             "embed": sh(v_ax, None), "lm_head": sh(v_ax, None), "final_norm": sh(),
             "layers": [layer] * a.num_layers,
         }
-        kinds = {m.kind for m in devices.flat[0].addressable_memories()}
-        self.host_sh = (
-            jax.tree.map(lambda s: s.with_memory_kind("pinned_host"), self.param_sh)
-            if "pinned_host" in kinds and devices.flat[0].platform != "cpu" else None
-        )
         self.batch_sh = sh("x", None)
         self._replicated = sh()
         self._init = jax.jit(lambda k: init_params(a, k), out_shardings=self.param_sh)
@@ -430,8 +426,25 @@ class TrainReference:
 
         return jax.tree.map(update, params, m, v), m, v, count
 
-    def _park(self, tree):
-        return tree if self.host_sh is None else jax.device_put(tree, self.host_sh)
+    @staticmethod
+    def _park(tree):
+        """Each leaf as its shards copied to the host: (shape, sharding,
+        [(device, numpy array), ...]). Bit for bit what the devices held."""
+        leaves, treedef = jax.tree.flatten(tree)
+        shards = [[(s.device, s.data) for s in x.addressable_shards] for x in leaves]
+        for leaf in shards:
+            for _, a in leaf:
+                a.copy_to_host_async()
+        return treedef, [(x.shape, x.sharding, [(d, np.asarray(a)) for d, a in leaf])
+                         for x, leaf in zip(leaves, shards)]
+
+    @staticmethod
+    def _unpark(parked):
+        treedef, leaves = parked
+        return jax.tree.unflatten(treedef, [
+            jax.make_array_from_single_device_arrays(
+                shape, sharding, [jax.device_put(h, d) for d, h in shards])
+            for shape, sharding, shards in leaves])
 
     def run(self, key, batches: list[dict]) -> dict:
         """Losses of each step, per-leaf norms of the first clipped
@@ -446,7 +459,7 @@ class TrainReference:
             losses.append(float(loss))
             if first_grad is None:
                 first_grad = {k: float(x) for k, x in gnorms.items()}
-            m, v = jax.device_put((m, v), (self.param_sh, self.param_sh))
+            m, v = self._unpark(m), self._unpark(v)
             params, m, v, count = self._update(params, m, v, count, g)
             del g
             m, v = self._park(m), self._park(v)

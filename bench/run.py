@@ -31,9 +31,11 @@ T_START = time.perf_counter()  # set-up is counted from the start of the process
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
+import faulthandler  # noqa: E402
 import functools  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
+import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -201,7 +203,9 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     print(f"compiles in window: {len(in_window)} {in_window}", flush=True)
     memory_peak = _memory_peak(devices)
     job.free()
+    t_check = time.perf_counter()
     checks = job.check()
+    print(f"bench: check {time.perf_counter() - t_check:.1f} s", file=sys.stderr, flush=True)
     correct = window["failed"] == 0 and all(c["value"] <= c["limit"] for c in checks)
 
     metrics: dict = {}
@@ -213,6 +217,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     if trace:
         from bench import trace as tr
 
+        t_reduce = time.perf_counter()
         reduced = tr.load(str(tdir))
         shutil.rmtree(tdir, ignore_errors=True)
         used = sorted(reduced.devices)
@@ -227,6 +232,8 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         result["breakdown"] = {"device_ops": tr.top_ops(reduced),
                                "idle_gaps": tr.idle_gaps(reduced)}
+        print(f"bench: trace reduction {time.perf_counter() - t_reduce:.1f} s", file=sys.stderr,
+              flush=True)
     else:
         for m in cell.end_to_end:
             value = setup_s if m["name"] == "setup_s" else window["metrics"].get(m["name"])
@@ -245,12 +252,17 @@ def main(argv=None) -> None:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
+    faulthandler.enable()  # a crash in the runtime prints the Python stack on stderr
     sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
     result, checks = run(ROOT, args.workload, args.seed, args.seconds, bool(args.trace))
     print(json.dumps(result), flush=True)
     for c in checks:
         print(f"check {c['name']}: {c['value']!r} (limit {c['limit']!r})", file=sys.stderr)
     sys.stderr.flush()
+    # Everything is printed and this process started no other. Leave without
+    # the interpreter's teardown, in which the TPU runtime's threads and the
+    # arrays still cached by JAX are destroyed in no fixed order.
+    os._exit(0)
 
 
 if __name__ == "__main__":

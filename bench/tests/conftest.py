@@ -26,19 +26,6 @@ SMALL_LIMITS = {
     "serve-chat-b4": {"mean_logit_gap": 0.035},
 }
 
-#: The training cell, which is not in BENCHMARK.json until it has run on
-#: four chips; its files are under bench/ and the small copy adds it back.
-TRAIN_CELL = {
-    "configs": {"name": "mixtral-8x7b-ep4-train",
-                "source": "https://huggingface.co/mistralai/Mixtral-8x7B-v0.1",
-                "file": "bench/configs/mixtral-8x7b-ep4-train.json",
-                "reduced": ["num_hidden_layers"], "why": "sparse experts over 4 chips"},
-    "workloads": {"name": "train-ep4-rails", "config": "mixtral-8x7b-ep4-train",
-                  "traffic": "train-8x1024-zipf", "chips": 4, "why": "rails all-to-all"},
-    "end_to_end": {"name": "train_tokens_per_s", "unit": "tokens/s", "better": "higher",
-                   "bound": 0.05, "source": "host_clock", "workloads": ["train-ep4-rails"]},
-}
-
 SMALL_MODEL = dict(d_model=128, num_heads=4, num_kv_heads=2, head_dim=32, d_ff=128,
                    moe_d_ff=128, vocab_size=512, xent_chunk=64)
 
@@ -51,10 +38,6 @@ def make_small_tree(dst: Path) -> Path:
     shutil.copy(ROOT / "BENCHMARK.json", dst / "BENCHMARK.json")
     (dst / "src").symlink_to(ROOT / "src")
     spec = json.loads((dst / "BENCHMARK.json").read_text())
-    for key, entry in TRAIN_CELL.items():
-        if entry["name"] not in {e["name"] for e in spec[key]}:
-            spec[key].append(entry)
-    (dst / "BENCHMARK.json").write_text(json.dumps(spec))
     for c in spec["configs"]:
         path = dst / c["file"]
         cfg = json.loads(path.read_text())

@@ -4,10 +4,11 @@ cell, mix, driver and metric added as new files only."""
 
 import hashlib
 import json
+import math
 import shutil
 from pathlib import Path
 
-from conftest import run_child
+from conftest import ROOT, run_child
 
 RUN = """
 import json, sys
@@ -35,6 +36,30 @@ def test_train_rehearsal_on_four_devices(small_tree):
     assert set(out["metrics"]) == {"train_tokens_per_s", "setup_s"}
     assert out["device"]["count"] == 4
     assert set(out["checks"]) == {"loss_rel", "grad_norm_gap", "delta_norm_gap"}
+
+
+def test_train_cell_reports_its_metrics():
+    """The training cell, as BENCHMARK.json has it, reports the training
+    rate and set-up end to end, and the training step's readers."""
+    from bench import run
+
+    cell = run.load_cell(ROOT, "train-ep4-rails")
+    assert cell.chips == 4 and cell.traffic["driver"] == "train"
+    assert {m["name"] for m in cell.end_to_end} == {"train_tokens_per_s", "setup_s"}
+    assert {m["name"] for m in cell.per_layer} == {
+        "idle_share.train", "train.mfu", "train.a2a_ms", "train.exposed_collective_share",
+        "train.attn_kernel_roofline", "train.attn_ms", "train.moe_ms", "train.head_ms",
+        "train.other_ms", "train.moe_a2a_ms"}
+    assert all(m["moves"] == "train_tokens_per_s" for m in cell.per_layer)
+    assert all((ROOT / "bench" / "metrics" / f"{m['name']}.py").is_file() for m in cell.per_layer)
+
+
+def test_train_cell_limits_are_set():
+    """The training check's limits, set from chip readings, are finite."""
+    limits = json.loads((ROOT / "bench" / "workloads" / "train-ep4-rails.json").read_text())
+    limits = limits["limits"]
+    assert set(limits) == {"loss_rel", "grad_norm_gap", "delta_norm_gap"}
+    assert all(isinstance(v, float) and math.isfinite(v) and v > 0 for v in limits.values())
 
 
 def _digest(root: Path) -> dict:
