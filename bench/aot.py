@@ -127,7 +127,8 @@ def serve(cell, devices) -> dict:
         cache = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), cache)
         tokens = {"tokens": jax.ShapeDtypeStruct((t["batch"], 1), jnp.int32, sharding=one)}
         pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
-        compiled = jax.jit(make_decode_step(cfg, ctx), donate_argnums=(1,)).lower(
+        compiled = jax.jit(make_decode_step(cfg, ctx, return_counts=True),
+                           donate_argnums=(1,)).lower(
             _abstract(params, p_sh), cache, tokens, pos).compile()
     out["program_decode_step"] = _bytes(compiled)
     arch = ref.Arch.from_model({**cell.model, **cell.config["assumed"]}, 1)

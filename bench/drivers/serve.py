@@ -11,6 +11,12 @@ so every request in the window is whole.
 ``tpot_ms_p95`` is the 95th percentile, over every decode step of every
 request in the window, of the host-clock time from one generated token's
 arrival to the next one's (one step yields one token per request).
+
+The step is built with the program's counters on
+(``make_decode_step(..., return_counts=True)``): each step also gives the
+(layer, expert) weight sets it read, which stay on the device until the
+window has closed and are then fetched at once; the bytes each step had to
+read are counted from them.
 """
 
 from __future__ import annotations
@@ -49,17 +55,22 @@ class Job:
         max_len = self.prompt_len + self.gen_len
         with jax.set_mesh(ctx.mesh):
             self.params, _ = init_sharded_params(cfg, ctx, self.key)
-            self.decode = jax.jit(make_decode_step(cfg, ctx), donate_argnums=(1,))
+            self.decode = jax.jit(make_decode_step(cfg, ctx, return_counts=True),
+                                  donate_argnums=(1,))
             self.new_cache = jax.jit(lambda: init_cache(cfg, self.batch, max_len))
         self.check_setup_s = 0.0
         self.served: list[tuple] = []  # (prompts, tokens, all logits finite)
+        self.reads: list = []  # each step's expert weight sets read, on the device
         self.batch_index = 0
         # Warm-up: every program the window drives, at the window's shapes.
         self._run_batch(prime=2, gen=2, keep=False)
 
     def _step(self, cache, tokens, pos):
         with jax.set_mesh(self.ctx.mesh), jax.profiler.TraceAnnotation("bench.step"):
-            return self.decode(self.params, cache, {"tokens": tokens}, jnp.int32(pos))
+            logits, cache, _, read = self.decode(self.params, cache, {"tokens": tokens},
+                                                 jnp.int32(pos))
+        self.reads.append(read)
+        return logits, cache
 
     @staticmethod
     def _argmax(logits):
@@ -96,12 +107,14 @@ class Job:
         t0 = time.perf_counter()
         deadline = t0 + seconds
         gaps, batches, positions = [], 0, []
+        self.reads = []
         while time.perf_counter() < deadline:
             times = self._run_batch(self.prompt_len, self.gen_len)
             batches += 1
             positions += list(range(self.prompt_len + self.gen_len))
             gaps += np.diff(times).tolist()
         elapsed = time.perf_counter() - t0
+        reads = [int(r) for r in jax.device_get(self.reads)]
         requests = batches * self.batch
         tokens = requests * self.gen_len
         vocab = self.cfg.vocab_size
@@ -118,7 +131,8 @@ class Job:
             "steps": len(positions),
             "step_module": f"jit_{self.decode.__name__}",
             "step_flops": [flops.decode_step_flops(m, self.batch, p) for p in positions],
-            "step_bytes": [flops.decode_step_bytes(m, self.batch, p) for p in positions],
+            "step_bytes": [flops.decode_step_bytes(m, self.batch, p, r)
+                           for p, r in zip(positions, reads)],
         }
 
     def free(self) -> None:

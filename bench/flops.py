@@ -18,6 +18,7 @@ __all__ = [
     "token_forward_flops",
     "train_step_flops",
     "decode_step_flops",
+    "expert_bytes",
     "decode_step_bytes",
     "flash_attention_call",
 ]
@@ -82,16 +83,25 @@ def _param_bytes_held(m: dict) -> int:
     return m["num_layers"] * per_layer + 2 * (m["vocab_size"] * d + d)
 
 
-def decode_step_bytes(m: dict, batch: int, pos: int) -> int:
+def expert_bytes(m: dict) -> int:
+    """Bytes of one expert's weights in one layer: its gate, up and down
+    projections (bfloat16)."""
+    return 2 * 3 * m["d_model"] * m["moe_d_ff"]
+
+
+def decode_step_bytes(m: dict, batch: int, pos: int, experts_read: int) -> int:
     """Least bytes one decode step at ``pos`` must read: every weight held
-    except the embedding table (``batch`` rows of it), and the KV cache up
-    to ``pos`` (bfloat16)."""
+    except the embedding table (``batch`` rows of it) and the experts no
+    token was routed to, and the KV cache up to ``pos`` (bfloat16).
+    ``experts_read`` is the count of (layer, expert) weight sets the step's
+    tokens were routed to, as the program counts them."""
     d, hkv, hd = m["d_model"], m["num_kv_heads"], _head_dim(m)
     window = m.get("sliding_window")
     seen = min(pos + 1, window) if window else pos + 1
     cache = m["num_layers"] * batch * seen * 2 * hkv * hd * 2
     embed_rows = batch * d * 2
-    return _param_bytes_held(m) + embed_rows + cache
+    unread = m["num_layers"] * m["num_experts"] - experts_read
+    return _param_bytes_held(m) - unread * expert_bytes(m) + embed_rows + cache
 
 
 def flash_attention_call(b: int, t: int, s: int, h: int, hkv: int, hd: int,

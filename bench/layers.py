@@ -103,7 +103,7 @@ def _serve_step_text(cell, devices) -> str:
         cache = jax.eval_shape(lambda: init_cache(cfg, t["batch"], t["prompt_len"] + t["gen_len"]))
         tokens = {"tokens": jax.ShapeDtypeStruct((t["batch"], 1), jnp.int32)}
         pos = jax.ShapeDtypeStruct((), jnp.int32)
-        step = jax.jit(make_decode_step(cfg, ctx), donate_argnums=(1,))
+        step = jax.jit(make_decode_step(cfg, ctx, return_counts=True), donate_argnums=(1,))
         return step.lower(params, cache, tokens, pos).compile().as_text()
 
 

@@ -1,8 +1,9 @@
 """Share of the chip's HBM bandwidth that the decode step reaches: the
 bytes each decode step must read (``bench.flops.decode_step_bytes``: every
-weight held and the KV cache up to its position), over the device time of
-the compiled decode step (its program's runs in the profiler trace) and
-the chip's peak bandwidth."""
+weight held but the experts none of its tokens was routed to, by the
+step's own count of the expert weight sets it read, and the KV cache up to
+its position), over the device time of the compiled decode step (its
+program's runs in the profiler trace) and the chip's peak bandwidth."""
 
 from bench.trace import mean_module
 

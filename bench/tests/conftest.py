@@ -1,7 +1,13 @@
 """Helpers for the benchmark's own tests: a copy of the benchmark cut to a
-size the CPU holds, and runs of it in a child process with virtual devices.
+size the CPU holds, the cells of a tree's ``BENCHMARK.json`` with their
+drivers, and runs of a copy in a child process with virtual devices.
 
 Run with ``python -m pytest bench/tests`` from the root of the checkout.
+
+Every cell's limits for the small copy are in its own limits file,
+``bench/workloads/<cell>.json``, under ``small_limits`` (beside the chip's
+``limits``), so a cell added as new files is covered here with no file
+edited.
 """
 
 from __future__ import annotations
@@ -18,25 +24,38 @@ import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 
-#: Limits of the small copy, set from CPU runs of it (program readings at
-#: most 5.6e-4 / 1.0e-2 / 7.4e-3 on loss / gradient / change, 0.06 on the
-#: logit gap; its float8 control read 3.5e-3 / 3.7e-2 / 9.3e-3 and 1.7).
-SMALL_LIMITS = {
-    "train-ep4-rails": {"loss_rel": 1.5e-3, "grad_norm_gap": 0.025, "delta_norm_gap": 0.025},
-    "serve-chat-b4": {"mean_logit_gap": 0.035},
-}
-
 SMALL_MODEL = dict(d_model=128, num_heads=4, num_kv_heads=2, head_dim=32, d_ff=128,
                    moe_d_ff=128, vocab_size=512, xent_chunk=64)
 
 
-def make_small_tree(dst: Path) -> Path:
-    """The benchmark's files with every configuration cut to CPU widths,
-    short requests and sequences, and the small copy's limits."""
-    shutil.copytree(ROOT / "bench", dst / "bench",
+def cells(root: Path = ROOT) -> list[dict]:
+    """Each cell of ``root``'s ``BENCHMARK.json``: its name, chips and the
+    driver its traffic mix names."""
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    out = []
+    for w in spec["workloads"]:
+        traffic = json.loads((root / "bench" / "traffic" / f"{w['traffic']}.json").read_text())
+        out.append({"name": w["name"], "chips": int(w["chips"]), "driver": traffic["driver"]})
+    return out
+
+
+def small_limits(root: Path, cell: str) -> dict:
+    """The small copy's limits of ``cell``, from its limits file in ``root``."""
+    path = root / "bench" / "workloads" / f"{cell}.json"
+    limits = json.loads(path.read_text()).get("small_limits")
+    if not limits:
+        raise KeyError(f"{path} has no small_limits: the limits of the benchmark's CPU copy")
+    return limits
+
+
+def make_small_tree(dst: Path, src: Path = ROOT) -> Path:
+    """The benchmark's files of the tree ``src`` with every configuration
+    cut to CPU widths, short requests and sequences, and each cell's
+    ``small_limits`` as its limits. ``src`` is left as it was."""
+    shutil.copytree(src / "bench", dst / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
-    shutil.copy(ROOT / "BENCHMARK.json", dst / "BENCHMARK.json")
-    (dst / "src").symlink_to(ROOT / "src")
+    shutil.copy(src / "BENCHMARK.json", dst / "BENCHMARK.json")
+    (dst / "src").symlink_to((src / "src").resolve())
     spec = json.loads((dst / "BENCHMARK.json").read_text())
     for c in spec["configs"]:
         path = dst / c["file"]
@@ -54,7 +73,7 @@ def make_small_tree(dst: Path) -> Path:
             t.update(prompt_len=8, gen_len=8)
         path.write_text(json.dumps(t))
         (dst / "bench" / "workloads" / f"{w['name']}.json").write_text(
-            json.dumps({"limits": SMALL_LIMITS[w["name"]]}))
+            json.dumps({"limits": small_limits(src, w["name"])}))
     return dst
 
 

@@ -1,6 +1,7 @@
 """CPU rehearsals of both drivers on the benchmark cut to CPU widths
-(Pallas kernels interpreted; four virtual devices for training), and a
-cell, mix, driver and metric added as new files only."""
+(Pallas kernels interpreted; four virtual devices for training), and
+cells, configurations, mixes, drivers and metrics added as new files
+only."""
 
 import hashlib
 import json
@@ -8,7 +9,8 @@ import math
 import shutil
 from pathlib import Path
 
-from conftest import ROOT, run_child
+from conftest import ROOT, cells, make_small_tree, run_child, small_limits
+from test_faults import control_cases, fault_cases
 
 RUN = """
 import json, sys
@@ -122,3 +124,83 @@ def test_refuses_a_cell_it_does_not_know(small_tree):
     shutil.rmtree(small_tree / "bench" / "workloads")
     with pytest.raises(run.Refused):
         run.load_cell(small_tree, "serve-chat-b4")
+
+
+def _cell_files(tree: Path, config: str, base_config: str, cell: str, base_cell: str,
+                traffic: str, base_traffic: str, **model) -> None:
+    """A configuration, a traffic mix and a limits file for ``cell``, each
+    a new file made from an existing one of its kind."""
+    bench = tree / "bench"
+    cfg = json.loads((bench / "configs" / f"{base_config}.json").read_text())
+    cfg["model"].update(model)
+    cfg.update(num_hidden_layers=model["num_layers"], num_local_experts=model["num_experts"])
+    (bench / "configs" / f"{config}.json").write_text(json.dumps(cfg))
+    mix = json.loads((bench / "traffic" / f"{base_traffic}.json").read_text())
+    mix["batch"] //= 2
+    (bench / "traffic" / f"{traffic}.json").write_text(json.dumps(mix))
+    limits = json.loads((bench / "workloads" / f"{base_cell}.json").read_text())
+    (bench / "workloads" / f"{cell}.json").write_text(
+        json.dumps({k: limits[k] for k in ("limits", "small_limits")}))
+
+
+def test_new_configuration_and_cells_are_new_files_only(tmp_path):
+    """A later PR adds a configuration of a second model with a four-chip
+    training cell and a one-chip serving cell: new configuration, traffic
+    and limits files, and entries appended to ``BENCHMARK.json``, all
+    before the small copy is made. Both cells rehearse ``correct``, report
+    their end-to-end metric, and get their faults and control; every file
+    the tree had stays byte for byte, and ``BENCHMARK.json`` only grows."""
+    tree = tmp_path / "tree"
+    shutil.copytree(ROOT / "bench", tree / "bench", ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", tree / "BENCHMARK.json")
+    (tree / "src").symlink_to(ROOT / "src")
+    before = _digest(tree)
+    old_spec = json.loads((tree / "BENCHMARK.json").read_text())
+
+    # 16 experts (4 a chip at ep = 4) in 1 layer, where the cells above run 8 in 2 or 4.
+    sizes = dict(num_layers=1, num_experts=16)
+    _cell_files(tree, "mixtral-16e-ep4-train", "mixtral-8x7b-ep4-train", "train-16e-ep4",
+                "train-ep4-rails", "train-4x1024-zipf", "train-8x1024-zipf", **sizes)
+    _cell_files(tree, "mixtral-16e-1chip-serve", "mixtral-8x7b-1chip-serve", "serve-16e-b2",
+                "serve-chat-b4", "chat-b2-p128-g128", "chat-b4-p128-g128", **sizes)
+    spec = json.loads((tree / "BENCHMARK.json").read_text())
+    new = {"train-16e-ep4": ("mixtral-16e-ep4-train", "train-4x1024-zipf", 4,
+                             "train_tokens_per_s"),
+           "serve-16e-b2": ("mixtral-16e-1chip-serve", "chat-b2-p128-g128", 1,
+                            "serve_tokens_per_s")}
+    for name, (config, traffic, chips, metric) in new.items():
+        spec["configs"].append({"name": config, "source": "https://example.org/16e",
+                                "file": f"bench/configs/{config}.json",
+                                "reduced": ["num_hidden_layers", "num_local_experts"],
+                                "why": "t"})
+        spec["workloads"].append({"name": name, "config": config, "traffic": traffic,
+                                  "chips": chips, "why": "t"})
+        next(m for m in spec["end_to_end"] if m["name"] == metric)["workloads"].append(name)
+    (tree / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    small = make_small_tree(tmp_path / "small", tree)
+
+    assert {(c["name"], c["chips"], c["driver"]) for c in cells(small)} >= {
+        ("train-16e-ep4", 4, "train"), ("serve-16e-b2", 1, "serve")}
+    assert {f for c, _, f in fault_cases(tree, "train") if c == "train-16e-ep4"} == {
+        "state_unchanged", "half_batch", "no_exchange"}
+    assert {f for c, _, f in fault_cases(tree, "serve") if c == "serve-16e-b2"} == {
+        "token_altered", "cache_unchanged"}
+    assert {("train-16e-ep4", 4), ("serve-16e-b2", 1)} <= set(control_cases(tree))
+    for name, (_, _, chips, metric) in new.items():
+        out = run_child(RUN.format(cell=name, seed=2**31 + 21, seconds=1.0), small,
+                        devices=chips)
+        assert out["correct"], out
+        assert out["attempted"] > 0 and out["failed"] == 0
+        assert set(out["metrics"]) == {metric, "setup_s"}
+        assert out["device"]["count"] == chips
+        assert set(out["checks"]) == set(small_limits(tree, name))
+
+    after = _digest(tree)
+    assert all(after[k] == v for k, v in before.items() if k != "BENCHMARK.json")
+    grown = json.loads((tree / "BENCHMARK.json").read_text())
+    for key in ("configs", "workloads", "per_layer"):
+        assert grown[key][:len(old_spec[key])] == old_spec[key]
+    for old, now in zip(old_spec["end_to_end"], grown["end_to_end"]):
+        assert {**now, "workloads": None} == {**old, "workloads": None}
+        assert now.get("workloads", [])[:len(old.get("workloads", []))] == old.get("workloads", [])

@@ -1,17 +1,58 @@
 """``correct`` comes out false for the control and for each fault a cell
-can have, at a size the CPU holds.
+can have, at a size the CPU holds, for every cell of ``BENCHMARK.json``.
 
-Each fault breaks the timed path underneath a whole run (the harness's
-look for a chip skipped): a training step that returns its state
-unchanged; half of each batch left out, the mean taken over the rest; the
-MoE exchange between chips left out; a served token altered where the
-decode step produces it, and a decode step that returns its cache
-unchanged. The control puts the plain reference, computed in float8, in
-the program's place."""
+The cases are drawn from ``BENCHMARK.json`` by each cell's driver, so a
+cell added as new files is covered with no file here edited. Each fault
+breaks the timed path underneath a whole run (the harness's look for a
+chip skipped), on as many virtual devices as the cell asks for chips:
+
+* every training cell: a training step that returns its state unchanged;
+  half of each batch left out, the mean taken over the rest;
+* a training cell on more than one chip: the MoE exchange between chips
+  left out;
+* every serving cell: a served token altered where the decode step
+  produces it; a decode step that returns its cache unchanged.
+
+The control, for every cell, puts the plain reference, computed in
+float8, in the program's place."""
 
 import pytest
 
-from conftest import SMALL_LIMITS, run_child
+from conftest import ROOT, cells, run_child, small_limits
+
+#: The faults of each driver's cells; a training cell on more than one
+#: chip can also lose the exchange between chips (``EXCHANGE``).
+FAULTS = {"train": ("state_unchanged", "half_batch"),
+          "serve": ("token_altered", "cache_unchanged")}
+EXCHANGE = "no_exchange"
+
+
+def fault_cases(root, driver: str) -> list:
+    """``(cell, chips, fault)`` for every fault of every cell of ``root``
+    whose traffic names ``driver``."""
+    out = []
+    for c in cells(root):
+        if c["driver"] != driver:
+            continue
+        faults = FAULTS[driver] + ((EXCHANGE,) if driver == "train" and c["chips"] > 1 else ())
+        out += [(c["name"], c["chips"], f) for f in faults]
+    return out
+
+
+def control_cases(root) -> list:
+    """``(cell, chips)`` of every cell of ``root``."""
+    return [(c["name"], c["chips"]) for c in cells(root)]
+
+
+def _params(cases):
+    return [pytest.param(*c, id="-".join(str(x) for x in c if not isinstance(x, int)))
+            for c in cases]
+
+
+def test_every_cell_has_its_faults():
+    """Every cell's driver is one whose faults these tests plant."""
+    assert all(c["driver"] in FAULTS for c in cells(ROOT)), cells(ROOT)
+
 
 TRAIN_FAULT = """
 import json
@@ -35,15 +76,16 @@ elif fault == "half_batch":
 elif fault == "no_exchange":
     moe._a2a = lambda payload, axis, cfg: payload
 from bench import run
-result, _ = run.run(Path('.'), 'train-ep4-rails', 7, 1.0, False, require_chip=False)
+result, _ = run.run(Path('.'), {cell!r}, 7, 1.0, False, require_chip=False)
 print(json.dumps(result))
 """
 
 
-@pytest.mark.parametrize("fault", ["state_unchanged", "half_batch", "no_exchange"])
-def test_train_fault_is_not_correct(small_tree, fault):
-    out = run_child(TRAIN_FAULT.format(fault=fault), small_tree, devices=4)
+@pytest.mark.parametrize("cell,chips,fault", _params(fault_cases(ROOT, "train")))
+def test_train_fault_is_not_correct(small_tree, cell, chips, fault):
+    out = run_child(TRAIN_FAULT.format(cell=cell, fault=fault), small_tree, devices=chips)
     assert out["correct"] is False, out["checks"]
+    assert set(out["checks"]) == set(small_limits(ROOT, cell))
     assert any(c["value"] > c["limit"] for c in out["checks"].values())
 
 
@@ -57,23 +99,25 @@ real = steps.make_decode_step
 def broken(cfg, ctx, **kw):
     step = real(cfg, ctx, **kw)
     def decode(params, cache, batch, pos):
-        logits, new_cache = step(params, cache, batch, pos)
+        logits, new_cache, *counts = step(params, cache, batch, pos)
         if fault == "token_altered":
             logits = logits.at[:, 3].set(jnp.where(pos == 9, 1e9, logits[:, 3]))
-            return logits, new_cache
-        return logits, cache
+        else:
+            new_cache = cache
+        return (logits, new_cache, *counts)
     return decode
 steps.make_decode_step = broken
 from bench import run
-result, _ = run.run(Path('.'), 'serve-chat-b4', 7, 1.0, False, require_chip=False)
+result, _ = run.run(Path('.'), {cell!r}, 7, 1.0, False, require_chip=False)
 print(json.dumps(result))
 """
 
 
-@pytest.mark.parametrize("fault", ["token_altered", "cache_unchanged"])
-def test_serve_fault_is_not_correct(small_tree, fault):
-    out = run_child(SERVE_FAULT.format(fault=fault), small_tree)
+@pytest.mark.parametrize("cell,chips,fault", _params(fault_cases(ROOT, "serve")))
+def test_serve_fault_is_not_correct(small_tree, cell, chips, fault):
+    out = run_child(SERVE_FAULT.format(cell=cell, fault=fault), small_tree, devices=chips)
     assert out["correct"] is False, out["checks"]
+    assert set(out["checks"]) == set(small_limits(ROOT, cell))
 
 
 CONTROL = """
@@ -103,10 +147,10 @@ print(json.dumps(rows))
 """
 
 
-@pytest.mark.parametrize("cell,devices", [("train-ep4-rails", 4), ("serve-chat-b4", 1)])
-def test_float8_control_is_not_correct(small_tree, cell, devices):
-    rows = run_child(CONTROL.format(cell=cell), small_tree, devices=devices)
+@pytest.mark.parametrize("cell,chips", _params(control_cases(ROOT)))
+def test_float8_control_is_not_correct(small_tree, cell, chips):
+    rows = run_child(CONTROL.format(cell=cell), small_tree, devices=chips)
     for checks in rows:
         assert any(c["value"] > c["limit"] for c in checks), checks
     assert set(rows[0][0]) == {"name", "value", "limit"}
-    assert {c["name"] for c in rows[0]} == set(SMALL_LIMITS[cell])
+    assert {c["name"] for c in rows[0]} == set(small_limits(ROOT, cell))

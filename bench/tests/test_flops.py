@@ -42,8 +42,20 @@ def test_decode_step():
     # (bf16) -> 2 * (32 + 16 + 72 + 8) = 256; router 4 * 4 * 2 = 32; embed and head
     # 2 * (5 * 4 + 4) = 48 -> 336 held. The step also reads 3 embedding rows
     # (3 * 4 * 2 = 24) and the cache of positions 0..2: 1 layer * 3 * 3 * (k, v)
-    # 2 * 1 head * 2 * 2 bytes = 72.
-    assert flops.decode_step_bytes(tiny, 3, 2) == 336 + 24 + 72
+    # 2 * 1 head * 2 * 2 bytes = 72; its tokens were routed to both experts.
+    assert flops.decode_step_bytes(tiny, 3, 2, experts_read=2) == 336 + 24 + 72
+
+
+def test_decode_step_bytes_of_the_experts_read():
+    # One expert of one layer at Mixtral widths: three 4096 x 14336 matrices
+    # in bfloat16, 352,321,536 bytes; 4 layers hold 32 of them.
+    m = dict(MIXTRAL, num_layers=4)
+    assert flops.expert_bytes(m) == 2 * 3 * 4096 * 14336 == 352_321_536
+    every = flops.decode_step_bytes(m, 4, 127, experts_read=32)
+    assert every - flops.decode_step_bytes(m, 4, 127, experts_read=22) == 10 * 352_321_536
+    # A step that read no expert still reads attention, router, norms, head,
+    # 4 embedding rows and the cache.
+    assert flops.decode_step_bytes(m, 4, 127, experts_read=0) == every - 32 * 352_321_536
 
 
 def test_flash_attention_call():

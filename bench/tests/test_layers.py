@@ -157,11 +157,13 @@ run._setup_jax()
 cell = run.load_cell(Path('.'), 'serve-chat-b4')
 devices = jax.devices()[:1]
 job = run.load_module(Path('bench/drivers/serve.py')).Job(cell, 2**31 + 5, devices)
+module = f'jit_{job.decode.__name__}'
 ran = [layers.scope_map(e.hlo_modules()[0].to_string())
        for e in devices[0].client.live_executables()
-       if e.hlo_modules()[0].name == 'jit_decode_step']
+       if e.hlo_modules()[0].name == module]
 rebuilt = layers.scope_map(layers._serve_step_text(cell, devices))
 print(json.dumps({
+    'module': module,
     'ran': len(ran),
     'same': all(m == rebuilt for m in ran),
     'layers': sorted({layers.layer_of(v) for v in rebuilt.values()} - {None}),
@@ -174,6 +176,7 @@ def test_rebuilt_step_is_the_program_the_driver_ran(small_tree):
     instructions, and their scopes, of every decode step the serve driver
     compiled and ran."""
     out = run_child(SAME_PROGRAM, small_tree)
+    assert out["module"] == "jit_decode_step_counts"  # the step with its counters
     assert out["ran"] >= 1 and out["same"], out
     assert out["layers"] == ["attn", "head", "moe"]
 
