@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .flash_attention import flash_attention_pallas
+from .flash_attention import BWD_BLOCK_K, flash_attention_pallas
 from .grouped_matmul import grouped_matmul_pallas
 from .moe_decode import routed_expert_ffn_pallas
 from .ref import flash_attention_ref, grouped_matmul_ref, rmsnorm_ref, routed_expert_ffn_ref
@@ -78,9 +78,11 @@ def flash_attention(
     window: Optional[int] = None,
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ) -> jnp.ndarray:
+    """Flash attention; ``block_q`` / ``block_k`` override the Pallas
+    kernel's shape-chosen tiles (``flash_attention.flash_tiles``)."""
     backend = kernel_backend()
     if backend in ("pallas", "interpret"):
         def kernel(q, k, v):
@@ -117,7 +119,7 @@ def flash_attention(
         window=window,
         softcap=softcap,
         scale=scale,
-        block_k=block_k,
+        block_k=BWD_BLOCK_K if block_k is None else block_k,
     )
 
 

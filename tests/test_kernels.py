@@ -6,7 +6,15 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.flash_attention import (
+    KV_ROWS,
+    QUERY_ROWS,
+    SUBLANES,
+    _kv_index_map,
+    flash_attention_pallas,
+    flash_tile_plan,
+    flash_tiles,
+)
 from repro.kernels.grouped_matmul import grouped_matmul_pallas
 from repro.kernels.moe_decode import routed_expert_ffn_pallas, routed_order
 from repro.kernels.ref import (
@@ -49,18 +57,33 @@ FLASH_CASES = [
     (2, 64, 64, 4, 2, 32, {"softcap": 30.0}),
     (1, 1, 40, 4, 2, 32, {"q_offset": 39}),  # decode
     (1, 50, 50, 2, 2, 16, {}),  # non-multiple-of-block sizes
+    # Shape-chosen tiles (``block_q``/``block_k`` None), a GQA group of 4:
+    # 256 query positions (1024 rows) by 512 keys, masked tiles skipped.
+    (2, 1024, 1024, 8, 2, 128, {"block_q": None, "block_k": None}),
+    (1, 1024, 1024, 4, 1, 64, {"window": 100, "block_q": None, "block_k": None}),
+    (1, 200, 700, 8, 2, 64, {"causal": False, "block_q": None, "block_k": None}),
+    # A group of 3 (phi4-mini's 24 / 8 heads): 256 positions, 768 rows a step.
+    (1, 600, 600, 6, 2, 64, {"block_q": None, "block_k": None}),
 ]
+
+
+def _tiles(kw):
+    """Split a case's kwargs into the kernel's tiles (32 unless the case
+    names them) and the attention options."""
+    kw = dict(kw)
+    return {"block_q": kw.pop("block_q", 32), "block_k": kw.pop("block_k", 32)}, kw
 
 
 @pytest.mark.parametrize("case", FLASH_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_matches_oracle(case, dtype):
     b, t, s, h, hkv, hd, kw = case
+    tiles, kw = _tiles(kw)
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(b, t, h, hd)), dtype)
     k = jnp.asarray(rng.normal(size=(b, s, hkv, hd)), dtype)
     v = jnp.asarray(rng.normal(size=(b, s, hkv, hd)), dtype)
-    got = flash_attention_pallas(q, k, v, block_q=32, block_k=32, interpret=True, **kw)
+    got = flash_attention_pallas(q, k, v, interpret=True, **tiles, **kw)
     want = flash_attention_ref(q, k, v, block_k=48, **kw)
     oracle = _naive_attention(q, k, v, **kw)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
@@ -70,6 +93,86 @@ def test_flash_attention_matches_oracle(case, dtype):
     np.testing.assert_allclose(
         want.astype(jnp.float32), oracle.astype(jnp.float32), atol=tol, rtol=tol
     )
+
+
+TILE_PLAN_CASES = [
+    # (t, s, block_q, block_k, causal, window, q_offset)
+    (1024, 1024, 256, 512, True, None, 0),
+    (1024, 1024, 128, 128, True, None, 0),
+    (1000, 1000, 256, 512, True, None, 0),  # neither a multiple of its tile
+    (1024, 1024, 256, 512, True, 100, 0),
+    (700, 700, 64, 96, True, 150, 0),
+    (96, 160, 32, 48, True, None, 64),  # a chunk after a prefix
+    (40, 200, 16, 64, True, 70, 150),
+    (200, 700, 64, 128, False, None, 0),  # cross attention
+    (300, 300, 64, 128, False, 50, 0),
+]
+
+
+def _unmasked(t, s, causal, window, q_offset):
+    q_pos = q_offset + np.arange(t)[:, None]
+    k_pos = np.arange(s)[None, :]
+    mask = np.ones((t, s), bool)
+    if causal:
+        mask &= k_pos <= q_pos
+    if window is not None:
+        mask &= q_pos - k_pos < window
+    return mask
+
+
+@pytest.mark.parametrize("case", TILE_PLAN_CASES)
+def test_flash_tile_plan_counts_tiles_with_an_unmasked_pair(case):
+    t, s, bq, bk, causal, window, q_offset = case
+    mask = _unmasked(t, s, causal, window, q_offset)
+    n_q, n_k = -(-t // bq), -(-s // bk)
+    needed = np.array([[mask[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk].any()
+                        for j in range(n_k)] for i in range(n_q)])
+    plan = flash_tile_plan(t, s, bq, bk, causal, window, q_offset)
+    assert plan.total == n_q * n_k
+    assert plan.computed == needed.sum()
+    for i in range(n_q):
+        cols = np.flatnonzero(needed[i])
+        assert (plan.first[i], plan.last[i]) == (cols[0], cols[-1])
+        assert needed[i, plan.first[i]:plan.last[i] + 1].all()
+
+
+@pytest.mark.parametrize("case", TILE_PLAN_CASES)
+def test_skipped_kv_steps_repeat_a_needed_tile(case):
+    """A step outside a query tile's needed KV tiles maps K/V to the
+    nearest needed tile, the index of the step before or after it, so
+    Pallas fetches nothing for it."""
+    t, s, bq, bk, causal, window, q_offset = case
+    plan = flash_tile_plan(t, s, bq, bk, causal, window, q_offset)
+    n_k = -(-s // bk)
+    index = _kv_index_map(t=t, seq_k=s, block_q=bq, block_k=bk, causal=causal,
+                          window=window, q_offset=q_offset)
+    for qi, (first, last) in enumerate(zip(plan.first, plan.last)):
+        got = [int(index(3, qi, ki)[1]) for ki in range(n_k)]
+        assert got == [min(max(ki, first), last) for ki in range(n_k)]
+        assert got[last + 1:] == [last] * (n_k - 1 - last)
+        assert int(index(3, qi, 0)[0]) == 3
+
+
+def test_flash_tiles_at_the_training_shape():
+    """Mixtral's training shape: 4 heads a group, 256 query positions
+    (1024 rows) by 512 keys, 6 of 8 steps computed under the causal mask."""
+    assert flash_tiles(1024, 1024, 4) == (256, 512)
+    assert flash_tiles(1024, 1024, 4, 32, 32) == (32, 32)
+    assert flash_tiles(50, 50, 1) == (64, 50)  # short: whole sequence, sublane multiple
+    assert flash_tiles(4096, 4096, 3) == (256, 512)  # phi4-mini: a group of 3
+    assert flash_tile_plan(1024, 1024, 256, 512)[2:] == (6, 8)
+
+
+@pytest.mark.parametrize("rep", [1, 2, 3, 4, 5, 6, 8, 12, 64, 100])
+def test_flash_tiles_query_block_is_a_power_of_two(rep):
+    """The shape-chosen query block is a power of two of at least
+    ``SUBLANES`` positions whose group fills at most ``QUERY_ROWS`` rows
+    (or at least ``SUBLANES``), so it divides power-of-two sequences and
+    meets the TPU's tiling of the block's second-minor dimension."""
+    block_q, block_k = flash_tiles(8192, 8192, rep)
+    assert block_q & (block_q - 1) == 0 and block_q >= SUBLANES
+    assert rep * block_q <= max(QUERY_ROWS, rep * SUBLANES) < 2 * rep * block_q
+    assert block_k == KV_ROWS
 
 
 GMM_CASES = [(1, 64, 32, 48), (4, 100, 64, 72), (8, 33, 17, 129)]
@@ -193,6 +296,7 @@ def test_routed_expert_ffn_vjp_matches_oracle_grad():
 @pytest.mark.parametrize("case", FLASH_CASES)
 def test_flash_attention_vjp_matches_oracle_grad(case):
     b, t, s, h, hkv, hd, kw = case
+    tiles, kw = _tiles(kw)
     rng = np.random.default_rng(3)
     q, k, v = (
         jnp.asarray(rng.normal(size=shape), jnp.float32)
@@ -204,9 +308,7 @@ def test_flash_attention_vjp_matches_oracle_grad(case):
         return lambda q, k, v: jnp.sum(attn(q, k, v) * probe)
 
     got = jax.grad(
-        loss(lambda q, k, v: flash_attention_pallas(
-            q, k, v, block_q=32, block_k=32, interpret=True, **kw
-        )),
+        loss(lambda q, k, v: flash_attention_pallas(q, k, v, interpret=True, **tiles, **kw)),
         argnums=(0, 1, 2),
     )(q, k, v)
     want = jax.grad(

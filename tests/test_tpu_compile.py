@@ -12,6 +12,7 @@ workers all import this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +73,40 @@ def test_flash_attention_fwd_and_vjp_compile(one_chip):
 
     hlo = _hlo(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
     assert "tpu_custom_call" in hlo
+
+
+TRAINING_ATTENTION = [
+    # (config, batch, seq, attention options)
+    ("mixtral-8x7b", 2, 1024, {}),  # train-ep4-rails: batch 8 over 4 chips
+    ("phi4-mini-3.8b", 1, 4096, {}),  # a group of 3 query heads a KV head
+    ("gemma2-9b", 1, 4096, {"softcap": 50.0, "window": 4096}),  # head_dim 256
+]
+
+
+@pytest.mark.parametrize("case", TRAINING_ATTENTION, ids=lambda c: c[0])
+def test_flash_attention_training_shape_is_one_three_operand_kernel(one_chip, case):
+    """A per-device training call at the configuration's widths with the
+    shape-chosen tiles: forward and VJP compile, so the tiles meet the
+    chip's block tiling and fit in VMEM, and the forward is exactly one
+    Pallas call with three operands (q, k, v), the call the benchmark's
+    ``train.attn_kernel_roofline`` picks out of a trace."""
+    name, b, t, kw = case
+    cfg = get_config(name)
+    hd = cfg.head_dim
+    q = jax.ShapeDtypeStruct((b, t, cfg.num_heads, hd), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, t, cfg.num_kv_heads, hd), jnp.bfloat16, sharding=one_chip)
+
+    def attn(q, k, v):
+        return flash_attention_pallas(q, k, v, **kw)
+
+    fwd = _hlo(attn, q, kv, kv)
+    calls = re.findall(r"custom-call\((.*?)\), custom_call_target=\"tpu_custom_call\"", fwd)
+    assert [c.count("%") for c in calls] == [3]
+
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v).astype(jnp.float32))
+
+    assert "tpu_custom_call" in _hlo(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
 
 
 @pytest.mark.parametrize("rows", [2048, 4])
